@@ -20,6 +20,7 @@ import subprocess
 
 from benchmarks import util
 from benchmarks.util import header
+from repro.compile_cache import use_compile_cache
 
 MODULES = (
     "fig06_bandwidth",
@@ -191,6 +192,7 @@ def main() -> None:
                          "shape), or --strategies hwc,swc,tc "
                          "(default: hwc,swc)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.fuse_steps < 1:
         ap.error("--fuse-steps must be >= 1")
     if args.smoke:

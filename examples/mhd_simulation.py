@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.physics.mhd import (
     AX, AZ, LNRHO, MHDParams, MHDSolver, SS, UX, UZ,
 )
@@ -39,6 +40,7 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=40)
     ap.add_argument("--amplitude", type=float, default=0.05)
     args = ap.parse_args()
+    use_compile_cache()
 
     solver = MHDSolver(
         (args.n,) * 3,

@@ -90,6 +90,9 @@ def lm_demo():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     stencil_demo()
     diffusion_demo()
     lm_demo()

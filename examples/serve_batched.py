@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.registry import get_config, get_model, reduced_config
 from repro.distrib import sharding as shlib
 from repro.launch.mesh import make_mesh
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--max-new", type=int, default=24)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = reduced_config(get_config(args.arch))
     api = get_model(cfg)

@@ -12,6 +12,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.ft.supervisor import StragglerMonitor
 from repro.launch.serve_sim import SimServer, demo_queue
 
@@ -25,6 +26,7 @@ def main() -> None:
                     help="inject a sleep into this batch index so the "
                          "straggler monitor fires (default: last batch)")
     args = ap.parse_args()
+    use_compile_cache()
 
     # Two interleaved request shapes -> two buckets; FIFO head-of-line
     # picks whichever bucket the oldest waiting request belongs to.

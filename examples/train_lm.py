@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import use_compile_cache
 from repro.configs.registry import get_config
 from repro.data import BatchIterator, MarkovLMDataset
 from repro.distrib import sharding as shlib
@@ -32,6 +33,7 @@ def main() -> None:
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--fail-at", type=int, default=120)
     args = ap.parse_args()
+    use_compile_cache()
 
     # ~15M params: a shrunken qwen2.5 (same family/topology).
     cfg = dataclasses.replace(

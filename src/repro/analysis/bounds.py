@@ -47,7 +47,7 @@ from repro.analysis.shadow import (
     shadow_shims,
     uncovered,
 )
-from repro.kernels.plan import StencilPlan
+from repro.kernels.plan import StencilPlan, staged_window
 
 
 @dataclasses.dataclass
@@ -169,11 +169,15 @@ def _audit_pipelined(
         plan.n_f * math.prod(observed[0])
         if plan.fuse_steps > 1 and observed else 0
     )
+    # Mosaic stages halo windows tile-aligned; the body reads only the
+    # logical window the shadow refs hold.
+    if plan.n_aux and plan.fuse_steps > 1:
+        aux_window = staged_window(aux_window)
     aux_sz = (
         plan.n_aux * math.prod(aux_window) if plan.n_aux else 0
     )
     return itemsize * (
-        2 * plan.n_f * math.prod(window)
+        2 * plan.n_f * math.prod(staged_window(window))
         + 2 * aux_sz
         + mid
         + plan.n_out * math.prod(out_tile)
@@ -200,9 +204,6 @@ def _audit_stream(
     ext = emit.stream_extents(plan)
     ts, hs = plan.block[0], plan.halo[0]
     n_chunks = ext["n_chunks"]
-    padded = tuple(
-        n + 2 * h for n, h in zip(plan.interior, plan.halo)
-    )
     cross_grid = tuple(
         n // t for n, t in zip(plan.interior[1:], plan.block[1:])
     )
@@ -211,15 +212,16 @@ def _audit_stream(
     )
     for corner in corners:
         exp_halo = tuple(
-            (c * t, c * t + t + 2 * h)
-            for c, t, h in zip(corner, plan.block[1:], plan.halo[1:])
+            (c * t, c * t + w)
+            for c, t, w in zip(corner, plan.block[1:], ext["cross"])
         )
         exp_tile = tuple(
             (c * t, (c + 1) * t)
             for c, t in zip(corner, plan.block[1:])
         )
         f_hbm = ShadowRef(
-            "f_hbm", (plan.n_f,) + padded, plan.dtype, initialized=True
+            "f_hbm", (plan.n_f,) + ext["padded"], plan.dtype,
+            initialized=True,
         )
         o_hbm = ShadowRef(
             "o_hbm", (plan.n_out,) + plan.interior, plan.dtype
@@ -326,7 +328,7 @@ def _audit_stream(
                     f_hbm, o_hbm, work, pf0, pf1, outbuf,
                     ShimSem(), ShimSem(),  # inert DMA semaphores
                     ops=ops, radii=plan.radii, tile=plan.block,
-                    phis=phis, n_chunks=n_chunks,
+                    phis=phis, n_chunks=n_chunks, cross=ext["cross"],
                 )
         except AuditError as e:
             findings.append(Finding(e.cls, sid, e.detail))

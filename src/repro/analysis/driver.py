@@ -36,6 +36,11 @@ from repro.analysis.keys import (
 from repro.analysis.vmem import check_vmem
 
 
+# The audited candidate space is ranked with the peaks of the chip the
+# kernels target, whatever host the audit runs on.
+AUDIT_DEVICE_KIND = "TPU v5 lite"
+
+
 def _git_sha() -> str:
     try:
         return subprocess.run(
@@ -77,7 +82,7 @@ def _candidate_plans(entry: Any, domain: tuple[int, ...]):
         fuse_steps_options=fuse_opts,
         stream_ok=not entry.n_aux,
         tc_ok=entry.dtype in ("float32", "bfloat16"),
-        backend="audit",
+        backend=AUDIT_DEVICE_KIND,
     )
     for c in cands:
         if c.strategy == "hwc":

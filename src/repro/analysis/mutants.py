@@ -118,7 +118,7 @@ def _make_kernel_stream_mutant(
 
     def kernel(
         f_hbm, o_hbm, work, pf0, pf1, outbuf, sem_pf, sem_out, *,
-        ops, radii, tile, phis, n_chunks,
+        ops, radii, tile, phis, n_chunks, cross,
     ):
         from repro.kernels import emit
 
@@ -129,10 +129,7 @@ def _make_kernel_stream_mutant(
         cross_off = tuple(
             pl.program_id(i) * tile[1 + i] for i in range(rank - 1)
         )
-        cross_halo = tuple(
-            pl.ds(o, t + 2 * h)
-            for o, t, h in zip(cross_off, tile[1:], halo[1:])
-        )
+        cross_halo = tuple(pl.ds(o, w) for o, w in zip(cross_off, cross))
         cross_tile = tuple(
             pl.ds(o, t) for o, t in zip(cross_off, tile[1:])
         )

@@ -22,14 +22,6 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def axis_size(axis_name: str) -> int:
-    """Mesh-axis size inside shard_map, across jax versions (shared by
-    the halo exchange and grad_sync)."""
-    if hasattr(lax, "axis_size"):  # jax >= 0.6
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def exchange_halo_1d(
     f: jnp.ndarray, radius: int, axis_name: str, *, axis: int
 ) -> jnp.ndarray:
@@ -41,7 +33,7 @@ def exchange_halo_1d(
     """
     if radius == 0:
         return f
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     del idx  # symmetry: same program on every shard
 

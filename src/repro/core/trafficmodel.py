@@ -40,6 +40,7 @@ model cannot diverge.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Sequence
 
 from repro.models.config import ModelConfig
@@ -298,88 +299,77 @@ def stencil_redundant_compute_fraction(
 # MXU compute model (the tc caching regime: stencils as banded matmuls).
 # ---------------------------------------------------------------------------
 
-# Peak matrix-unit FLOP/s at f32 accumulation with f32 inputs, keyed by
-# a substring of the JAX device_kind / backend description. bf16 inputs
-# run the MXU at double rate (see :func:`peak_mxu_flops`). The default
-# matches ``repro.core.rooflinelib.TPU_V5E``.
-PEAK_MXU_FLOPS_F32: dict[str, float] = {
-    "v4": 137.5e12,
-    "v5e": 98.5e12,
-    "v5p": 229.5e12,
-    "v6e": 459.5e12,
-}
-DEFAULT_PEAK_MXU_FLOPS_F32 = 98.5e12  # v5e-class
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """One device's peak rates, as the cost model reads them."""
 
-# Peak HBM bandwidth (bytes/s) per platform, same keying; used to
-# normalize the tc compute term against the bandwidth roof the traffic
-# scores are expressed in.
-PEAK_HBM_BW: dict[str, float] = {
-    "v4": 1228e9,
-    "v5e": 819e9,
-    "v5p": 2765e9,
-    "v6e": 1640e9,
+    mxu_flops_f32: float  # matrix unit, f32 inputs, f32 accumulate
+    hbm_bw: float  # HBM bytes/s
+    # Vector unit (VPU) f32 FLOP/s: ~a quarter of the MXU f32 rate (8×128
+    # lanes × 2 ALU slots vs the 128×128 systolic array) — the rate the
+    # tap-by-tap swc/swc_stream/hwc regimes run their multiply-adds at.
+    vpu_flops_f32: float
+
+
+# Keyed by the exact ``device_kind`` JAX reports. MXU and HBM: Google
+# Cloud TPU documentation per generation (bf16 peak halved for f32
+# inputs; v5e: 197 TFLOP/s bf16, 819 GB/s). VPU: a quarter of the MXU
+# f32 rate (estimate, not published). ``"cpu"`` is the interpret-mode
+# host the tests run on: it models the TPU v5e the kernels are written
+# for, so CPU runs rank candidates as that chip would.
+_V5E = ChipPeaks(98.5e12, 819e9, 24.625e12)
+DEVICE_PEAKS: dict[str, ChipPeaks] = {
+    "TPU v4": ChipPeaks(137.5e12, 1228e9, 34.375e12),
+    "TPU v5 lite": _V5E,
+    "TPU v5": ChipPeaks(229.5e12, 2765e9, 57.375e12),
+    "TPU v6 lite": ChipPeaks(459.5e12, 1640e9, 114.875e12),
+    "cpu": _V5E,
 }
-DEFAULT_PEAK_HBM_BW = 819e9  # v5e-class
+
+
+def device_peaks(backend: str | None = None) -> ChipPeaks:
+    """Peak rates of ``backend`` (a JAX ``device_kind``; None → the
+    default device's). A device that is not in :data:`DEVICE_PEAKS` is
+    an error, never a default.
+
+    Raises:
+        KeyError: for a device kind with no row.
+    """
+    if backend is None:
+        import jax
+
+        backend = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[backend]
+    except KeyError:
+        raise KeyError(
+            f"no peak rates for device kind {backend!r}; add a row to "
+            f"DEVICE_PEAKS (known: {sorted(DEVICE_PEAKS)})"
+        ) from None
 
 
 def peak_mxu_flops(
     backend: str | None = None, itemsize: int = 4
 ) -> float:
-    """Platform peak MXU FLOP/s for the given input itemsize.
-
-    ``backend`` is matched as a substring against the platform table
-    (e.g. a device_kind like ``"TPU v5e"``); unknown/None falls back to
-    the v5e-class default. bf16 inputs (itemsize 2) double the rate —
-    the f32-accumulate contract the tc emitter lowers with.
-    """
-    base = DEFAULT_PEAK_MXU_FLOPS_F32
-    if backend:
-        b = backend.lower()
-        for key, v in PEAK_MXU_FLOPS_F32.items():
-            if key in b:
-                base = v
-                break
+    """Peak MXU FLOP/s of ``backend`` for the given input itemsize: bf16
+    inputs (itemsize 2) run at double the f32 rate — the f32-accumulate
+    contract the tc emitter lowers with."""
+    base = device_peaks(backend).mxu_flops_f32
     return base * (2.0 if itemsize == 2 else 1.0)
 
 
 def peak_hbm_bw(backend: str | None = None) -> float:
-    """Platform peak HBM bandwidth (bytes/s), same substring matching
-    as :func:`peak_mxu_flops`."""
-    if backend:
-        b = backend.lower()
-        for key, v in PEAK_HBM_BW.items():
-            if key in b:
-                return v
-    return DEFAULT_PEAK_HBM_BW
-
-
-# Peak vector-unit (VPU) FLOP/s at f32, same keying as the MXU table.
-# TPU VPUs sustain roughly a quarter of the matrix-unit f32 rate (8×128
-# lanes × 2 ALU slots vs the 128×128 systolic array), which is the rate
-# the tap-by-tap swc/swc_stream/hwc regimes run their multiply-adds at.
-# The generalized-order cost model normalizes per-point stencil FLOPs
-# against this roof to weigh temporal fusion's redundant halo compute —
-# an order-2 operator (few taps) tolerates deep fusion where an order-8
-# one (4× the taps) may not.
-PEAK_VPU_FLOPS_F32: dict[str, float] = {
-    "v4": 34.375e12,
-    "v5e": 24.625e12,
-    "v5p": 57.375e12,
-    "v6e": 114.875e12,
-}
-DEFAULT_PEAK_VPU_FLOPS_F32 = 24.625e12  # v5e-class
+    """Peak HBM bandwidth (bytes/s) of ``backend``."""
+    return device_peaks(backend).hbm_bw
 
 
 def peak_vpu_flops(backend: str | None = None) -> float:
-    """Platform peak VPU (vector unit) FLOP/s, same substring matching
-    as :func:`peak_mxu_flops`. Element-wise rate is dtype-agnostic on
-    the f32-wide VPU, so there is no itemsize scaling."""
-    if backend:
-        b = backend.lower()
-        for key, v in PEAK_VPU_FLOPS_F32.items():
-            if key in b:
-                return v
-    return DEFAULT_PEAK_VPU_FLOPS_F32
+    """Peak VPU FLOP/s of ``backend``. The element-wise rate is
+    dtype-agnostic on the f32-wide VPU, so there is no itemsize
+    scaling. The generalized-order cost model normalizes per-point
+    stencil FLOPs against it to weigh temporal fusion's redundant halo
+    compute."""
+    return device_peaks(backend).vpu_flops_f32
 
 
 def stencil_mxu_flops_per_step(

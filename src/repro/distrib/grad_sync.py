@@ -69,9 +69,7 @@ def hierarchical_psum(
     Equivalent to psum over both axes; moves only 1/|fast| of the bytes
     over the slow (cross-pod) links.
     """
-    from repro.core.halo import axis_size
-
-    n_fast = axis_size(fast_axis)
+    n_fast = jax.lax.axis_size(fast_axis)
     orig_shape = x.shape
     pad = (-x.shape[0]) % n_fast
     if pad:

@@ -1,11 +1,20 @@
-"""Pallas API compatibility across jax versions.
+"""Overlapping-window BlockSpecs for the Pallas TPU lowering.
 
 The kernels express overlapping input windows (block + halo) with
-per-element block offsets. Newer jax spells this ``pl.Element`` per
-dimension; jax <= 0.4.x spells it ``indexing_mode=pl.Unblocked()`` for
-the whole spec. Both semantics are identical for our specs because the
-non-window dimensions always use offset 0 (full extent) or a squeezed
-``None`` dim, where block index == element offset.
+per-element block offsets (``pl.Element``). Mosaic puts two rules on
+such a spec:
+
+* every dimension is an ``Element`` (or squeezed) once any one is, so
+  full-extent dimensions are spelled ``Element(extent)`` at offset 0;
+* the last two block dimensions must be multiples of the (8, 128)
+  sublane × lane tiling or equal the array's extent (rank-1 blocks: the
+  lane tiling only).
+
+A halo window (τ + 2r) is almost never tile-aligned, so each window
+dimension among the last two is *staged* at the next tile multiple, and
+``Element`` padding lets the last window overhang the array's end.
+Kernel bodies read only the leading τ + 2r of the staged block (every
+tap slice is an explicit offset), so the overhang is never consumed.
 """
 from __future__ import annotations
 
@@ -13,7 +22,7 @@ from typing import Callable, Sequence
 
 from jax.experimental import pallas as pl
 
-HAS_ELEMENT = hasattr(pl, "Element")
+from repro.kernels.plan import tile_aligned, tile_multiples
 
 
 def element_window_spec(
@@ -23,13 +32,16 @@ def element_window_spec(
 ) -> pl.BlockSpec:
     """BlockSpec whose ``window_dims`` take *element* offsets from the
     index map (overlapping halo windows); remaining dims span the full
-    extent (or are squeezed with ``None``)."""
-    if HAS_ELEMENT:
-        shape = tuple(
-            pl.Element(s) if d in window_dims and s is not None else s
-            for d, s in enumerate(block_shape)
-        )
-        return pl.BlockSpec(shape, index_map)
-    return pl.BlockSpec(
-        tuple(block_shape), index_map, indexing_mode=pl.Unblocked()
-    )
+    extent at offset 0 (or are squeezed with ``None``). Window dims are
+    staged tile-aligned (module docstring)."""
+    tiles = tile_multiples(len(block_shape))
+    dims: list = []
+    for d, (s, tile) in enumerate(zip(block_shape, tiles)):
+        if s is None:
+            dims.append(None)
+        elif d in window_dims:
+            staged = tile_aligned(s, tile)
+            dims.append(pl.Element(staged, (0, staged - s)))
+        else:
+            dims.append(pl.Element(s))
+    return pl.BlockSpec(tuple(dims), index_map)

@@ -67,7 +67,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.stencil import OperatorSet
 from repro.kernels.compat import element_window_spec
-from repro.kernels.plan import StencilPlan, tc_axis_groups
+from repro.kernels.plan import (
+    VMEM_LIMIT_BYTES,
+    StencilPlan,
+    staged_window,
+    tc_axis_groups,
+)
+
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _block_derivs(
@@ -109,10 +116,18 @@ def _contract(window, band, axis: int):
     shadow = getattr(window, "shadow_contract", None)
     if shadow is not None:
         return shadow(band, axis)
+    # f32 windows contract at full f32 precision, so tc matches the VPU
+    # regimes to rounding; bf16 windows take the MXU's native pass.
+    precision = (
+        jax.lax.Precision.HIGHEST
+        if window.dtype == jnp.float32
+        else jax.lax.Precision.DEFAULT
+    )
     term = jax.lax.dot_general(
         window,
         band,
         dimension_numbers=(((1 + axis,), (0,)), ((), ())),
+        precision=precision,
         preferred_element_type=jnp.float32,
     )
     # dot_general appends the band's output dim last; put it back where
@@ -293,7 +308,15 @@ def _kernel_temporal(
     aux_ref, o_ref = rest
     n_steps = len(phis)
     cur = f_ref[...]
-    cur_aux = aux_ref[...]
+    # The staged aux block may be tile-aligned past the r·(S-1)-widened
+    # window (compat.element_window_spec): read the window only.
+    cur_aux = aux_ref[
+        (slice(None),)
+        + tuple(
+            slice(0, t + 2 * r * (n_steps - 1))
+            for t, r in zip(tile, radii)
+        )
+    ]
     for s, phi in enumerate(phis):  # static: unrolled at trace time
         margin = n_steps - 1 - s  # sweeps remaining after this one
         sub_tile = tuple(
@@ -426,14 +449,25 @@ def stream_extents(plan: StencilPlan) -> dict[str, tuple[int, ...] | int]:
     shared by :func:`_fused_stream` (VMEM scratch allocation) and the
     auditor's shadow run, like :func:`lowering_windows` for the
     pipelined path. Spatial extents only (``work``/``prefetch``/
-    ``outbuf``), plus the stream chunk count ``n_chunks``.
+    ``outbuf``, the staged ``cross`` window and the ``padded`` operand
+    the DMAs address), plus the stream chunk count ``n_chunks``.
     """
     tile, halo = plan.block, plan.halo
-    cross = tuple(t + 2 * h for t, h in zip(tile[1:], halo[1:]))
+    # A DMA slice must be tile-aligned on the (sublane, lane) axes, so
+    # the cross-stream window is staged aligned, and the operand is
+    # padded on its high side until the last window fits (``padded``).
+    cross = staged_window(
+        tuple(t + 2 * h for t, h in zip(tile[1:], halo[1:]))
+    )
+    padded = (plan.interior[0] + 2 * halo[0],) + tuple(
+        n - t + w for n, t, w in zip(plan.interior[1:], tile[1:], cross)
+    )
     return {
         "work": (tile[0] + 2 * halo[0],) + cross,
         "prefetch": (tile[0],) + cross,
         "outbuf": tile,
+        "cross": cross,
+        "padded": padded,
         "n_chunks": plan.interior[0] // tile[0],
     }
 
@@ -570,6 +604,7 @@ def fused_stencil_pallas(
         out_shape=jax.ShapeDtypeStruct(
             (plan.n_out,) + plan.interior, f_padded.dtype
         ),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*operands)
 
@@ -584,7 +619,7 @@ def fused_stencil_pallas(
 
 def _kernel_stream(
     f_hbm, o_hbm, work, pf0, pf1, outbuf, sem_pf, sem_out, *,
-    ops, radii, tile, phis, n_chunks,
+    ops, radii, tile, phis, n_chunks, cross,
 ):
     """Grid step = one cross-stream tile; the kernel streams all chunks
     of the slowest axis (z at rank 3, y at rank 2) through VMEM.
@@ -610,10 +645,7 @@ def _kernel_stream(
     cross_off = tuple(
         pl.program_id(i) * tile[1 + i] for i in range(rank - 1)
     )
-    cross_halo = tuple(
-        pl.ds(o, t + 2 * h)
-        for o, t, h in zip(cross_off, tile[1:], halo[1:])
-    )
+    cross_halo = tuple(pl.ds(o, w) for o, w in zip(cross_off, cross))
     cross_tile = tuple(
         pl.ds(o, t) for o, t in zip(cross_off, tile[1:])
     )
@@ -694,8 +726,14 @@ def _fused_stream(
 
     kernel = functools.partial(
         _kernel_stream, ops=ops, radii=plan.radii, tile=tile,
-        phis=phis, n_chunks=ext["n_chunks"],
+        phis=phis, n_chunks=ext["n_chunks"], cross=ext["cross"],
     )
+    grow = [(0, 0)] + [
+        (0, want - have)
+        for want, have in zip(ext["padded"], f_padded.shape[1:])
+    ]
+    if any(hi for _, hi in grow):
+        f_padded = jnp.pad(f_padded, grow)
     return pl.pallas_call(
         kernel,
         grid=tuple(n // t for n, t in zip(plan.interior[1:], tile[1:])),
@@ -712,5 +750,6 @@ def _fused_stream(
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA,
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(f_padded)

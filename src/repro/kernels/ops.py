@@ -7,8 +7,8 @@ matrix-unit contractions, plus the compiler-managed ``"hwc"`` baseline),
 and ``"auto"`` block resolution through ``repro.tuning``, so callers
 (fusion engine, physics, models) never touch BlockSpecs.
 
-On CPU (this container) ``interpret`` defaults to True; on TPU it
-defaults to False. Override explicitly for tests.
+On the CPU backend ``interpret`` defaults to True; on TPU it defaults
+to False; any other backend raises. Override explicitly for tests.
 
 Block parameters accept ``"auto"``: the persistent tuning cache
 (``repro.tuning``) is consulted, and on a miss with concrete operands
@@ -34,7 +34,17 @@ from repro.kernels.stencil1d import xcorr1d_pallas  # repolint: allow[legacy-ker
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret Pallas kernels on the CPU backend (where the tests
+    run) and compile them with Mosaic on a TPU. Any other backend is an
+    error: it must not quietly run the interpreter in place of the
+    chip."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas stencil kernels run on 'tpu' (Mosaic) or 'cpu' "
+            f"(interpret mode), not on backend {backend!r}"
+        )
+    return backend == "cpu"
 
 
 def _round_up(n: int, m: int) -> int:

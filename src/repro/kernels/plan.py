@@ -56,6 +56,55 @@ DEFAULT_BLOCKS: dict[int, tuple[int, ...]] = {
 }
 
 
+# Scoped VMEM each fused-stencil kernel may use, handed to Mosaic as
+# ``vmem_limit_bytes`` (its default is 16 MiB). A TPU v5e core has 128
+# MiB of VMEM. The tuner budgets only the staged blocks against an
+# eighth of it (``repro.tuning.costmodel.VMEM_BUDGET``); the rest holds
+# φ's temporaries, which dominate for the MHD φ: its depth-2 RK-pair
+# kernel at block (2, 8, 128) needs ~81 MiB.
+VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+
+# Mosaic's (sublane, lane) tiling of the last two dims of a 32-bit
+# VMEM block. A staged halo window (τ + 2r per axis) is rounded up to
+# it on those two spatial axes; see :func:`staged_window`.
+SUBLANE = 8
+LANE = 128
+
+
+def tile_aligned(extent: int, multiple: int) -> int:
+    """``extent`` rounded up to a multiple of ``multiple``."""
+    return -(-extent // multiple) * multiple
+
+
+def tile_multiples(rank: int) -> tuple[int, ...]:
+    """Multiple Mosaic requires of each axis of a rank-``rank`` block:
+    (…, 1, 8, 128) — sublane then lane on the last two axes."""
+    return ((1,) * rank + (SUBLANE, LANE))[-rank:]
+
+
+def staged_window(window: Sequence[int]) -> tuple[int, ...]:
+    """Extents Mosaic stages for a spatial halo ``window`` (x last): the
+    last (lane) axis rounded up to a multiple of 128 and, at rank ≥ 2,
+    the one before it (sublane) to a multiple of 8. Kernel bodies read
+    only the leading ``window`` of the staged block — the ONE alignment
+    rule shared by the emitter, the VMEM model and the auditor."""
+    return tuple(
+        tile_aligned(w, t)
+        for w, t in zip(window, tile_multiples(len(window)))
+    )
+
+
+def tpu_tile_ok(block: Sequence[int], interior: Sequence[int]) -> bool:
+    """Whether Mosaic accepts ``block`` as an output tile of a domain
+    with ``interior`` extents: the last axis a multiple of 128 and, at
+    rank ≥ 2, the one before it a multiple of 8 — or either equal to
+    the full extent. The tuner discards other tiles statically."""
+    return all(
+        t % m == 0 or t == n
+        for t, m, n in zip(block, tile_multiples(len(block)), interior)
+    )
+
+
 def largest_divisor_leq(n: int, cap: int) -> int:
     """Largest divisor of ``n`` that is ≤ ``cap`` (≥ 1)."""
     for t in range(min(cap, n), 0, -1):

@@ -19,12 +19,8 @@ import jax
 
 
 def _mesh_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on newer jax (>= 0.6); older versions
-    treat every axis as Auto already."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+    """Every axis Auto: the sharding rules let XLA propagate layouts."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -15,6 +15,8 @@ import argparse
 import sys
 import time
 
+from repro.compile_cache import use_compile_cache
+
 
 def _cache():
     from repro.tuning.cache import TuningCache
@@ -106,6 +108,7 @@ def main(argv=None) -> int:
     clear = sub.add_parser("clear", help="delete the cache")
     clear.set_defaults(fn=cmd_clear)
     args = ap.parse_args(argv)
+    use_compile_cache()
     return args.fn(args)
 
 

@@ -38,15 +38,21 @@ from repro.core.trafficmodel import (
     stencil_redundant_compute_fraction,
     stencil_stream_hbm_bytes_per_step,
 )
-from repro.kernels.plan import TC_MAX_TILE
+from repro.kernels.plan import (
+    LANE,
+    SUBLANE,
+    TC_MAX_TILE,
+    VMEM_LIMIT_BYTES,
+    staged_window,
+    tpu_tile_ok,
+)
 
 log = logging.getLogger("repro.tuning")
 
-# Conservative per-core VMEM budget (bytes). v4/v5 expose ~16 MiB per
-# core to Pallas; we leave headroom for the output block + spills.
-VMEM_BUDGET = 12 * 1024 * 1024
-LANE = 128
-SUBLANE = 8
+# VMEM the staged blocks of one candidate may take (bytes): an eighth
+# of the scoped limit the emitter hands Mosaic, leaving the rest for
+# φ's temporaries, which this model does not count.
+VMEM_BUDGET = VMEM_LIMIT_BYTES // 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -134,6 +140,10 @@ def vmem_working_set(
     every field-count term scales by B — which is why the batched
     candidate enumeration picks smaller blocks at larger B.
 
+    Halo windows count at the tile-aligned extents Mosaic stages
+    (:func:`~repro.kernels.plan.staged_window`): the lane axis rounded
+    up to 128 and the sublane axis to 8.
+
     ``unroll`` is the element-wise unroll factor of a pipelined plan:
     the staged window and output tile span all ``unroll`` x sub-tiles
     per grid step (``τx·unroll + 2r`` / ``τx·unroll``), so an unrolled
@@ -149,28 +159,35 @@ def vmem_working_set(
     """
     if batch < 1:
         raise ValueError(f"batch must be >= 1, got {batch}")
+    block, radii = tuple(block), tuple(radii)
     n_f = n_f * batch
     n_out = n_out * batch
     n_aux = n_aux * batch
+    last = len(block) - 1
+    steps = tuple(
+        t * unroll if a == last and not stream else t
+        for a, t in enumerate(block)
+    )
+    halo_win = tuple(
+        s + 2 * r * fuse_steps for s, r in zip(steps, radii)
+    )
+    carry_win = tuple(
+        t + 2 * r * (fuse_steps - 1) for t, r in zip(block, radii)
+    )
+    mid = (n_f if fuse_steps > 1 else 0) * math.prod(carry_win)
+    out = n_out * math.prod(steps)
     if stream:
-        work, pf, mid, out = n_f, n_f, n_f if fuse_steps > 1 else 0, n_out
-        for a, (t, r) in enumerate(zip(block, radii)):
-            work *= t + 2 * r * fuse_steps
-            pf *= t if a == 0 else t + 2 * r * fuse_steps
-            mid *= t + 2 * r * (fuse_steps - 1)
-            out *= t
+        cross = staged_window(halo_win[1:])
+        work = n_f * halo_win[0] * math.prod(cross)
+        pf = n_f * block[0] * math.prod(cross)
         return (work + 2 * pf + mid + out) * itemsize
-    last = len(tuple(block)) - 1
-    inp = n_f
-    mid = n_f if fuse_steps > 1 else 0
-    aux = n_aux
-    out = n_out
-    for a, (t, r) in enumerate(zip(block, radii)):
-        step = t * unroll if a == last else t
-        inp *= step + 2 * r * fuse_steps
-        mid *= t + 2 * r * (fuse_steps - 1)
-        aux *= step if fuse_steps == 1 else t + 2 * r * (fuse_steps - 1)
-        out *= step
+    # Halo windows are staged tile-aligned (plan.staged_window); a
+    # depth-1 aux operand is a halo-free output-shaped tile.
+    inp = n_f * math.prod(staged_window(halo_win))
+    aux = n_aux * (
+        math.prod(steps) if fuse_steps == 1
+        else math.prod(staged_window(carry_win))
+    )
     # Pallas double-buffers pipelined input blocks: 2x input (and aux).
     return (2 * inp + 2 * aux + mid + out) * itemsize
 
@@ -217,7 +234,7 @@ def enumerate_candidates_nd(
     batch: int = 1,
     flops_per_point: float | None = None,
 ) -> list[Candidate]:
-    """Generate, filter (divisibility + VMEM + the tiny-block guard),
+    """Generate, filter (divisibility + Mosaic tile layout + VMEM + the tiny-block guard),
     and rank (block, fuse_steps, stream) configurations for a
     rank-1/2/3 domain (the planner's search space — blocks are listed
     in axis order, x last). ``axis_options`` overrides the per-axis
@@ -304,6 +321,8 @@ def enumerate_candidates_nd(
                 if not ok:
                     continue
                 blk = tuple(blk)
+                if not tpu_tile_ok(blk, domain):
+                    continue  # Mosaic rejects the tile's layout
                 if tc and any(t > TC_MAX_TILE for t in blk):
                     continue  # contraction extent (→ FLOPs) unbounded
                 if stream and fuse > 1 and (
@@ -648,7 +667,10 @@ def autotune(
         except Exception as e:
             # The paper's discarded launch: log which candidate died
             # and why, then keep ranking the rest.
-            log.debug("autotune candidate %s discarded: %s", cand.block, e)
+            log.warning(
+                "autotune candidate %s discarded: %s: %s",
+                cand.block, type(e).__name__, e,
+            )
             continue
         timings[cand.block] = t
         if best is None or t < best[0]:

@@ -203,7 +203,7 @@ def test_candidate_enumeration_depends_on_batch():
     from repro.tuning import enumerate_candidates_nd, vmem_working_set
 
     domain, radii = (64, 128), (1, 1)
-    budget = 512 * 1024
+    budget = 2 * 1024 * 1024
     c1 = enumerate_candidates_nd(
         domain, radii, n_f=4, n_out=4, itemsize=4, vmem_budget=budget
     )

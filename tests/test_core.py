@@ -88,12 +88,18 @@ def test_operator_set_rejects_duplicate_names():
 
 
 def test_vmem_filter_discards_oversized_blocks():
+    budget = 4 * 1024 * 1024
     cands = enumerate_candidates_nd(
         (256, 256, 256), (3, 3, 3), n_f=8, n_out=8, itemsize=4,
-        vmem_budget=2 * 1024 * 1024,
+        vmem_budget=budget,
     )
     assert cands, "some candidate must fit"
-    assert all(c.vmem_bytes <= 2 * 1024 * 1024 for c in cands)
+    assert all(c.vmem_bytes <= budget for c in cands)
+    roomier = enumerate_candidates_nd(
+        (256, 256, 256), (3, 3, 3), n_f=8, n_out=8, itemsize=4,
+        vmem_budget=4 * budget,
+    )
+    assert len(roomier) > len(cands), "the filter discarded nothing"
     # candidate accounting agrees with the working-set formula
     assert all(
         c.vmem_bytes == vmem_working_set(c.block, (3, 3, 3), 8, 8, 4)

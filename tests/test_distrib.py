@@ -23,14 +23,8 @@ from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    # jax <= 0.4.x spells it jax.experimental.shard_map with check_rep.
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def test_sharded_stencil_matches_single_device():

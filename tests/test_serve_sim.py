@@ -160,3 +160,40 @@ def test_server_matches_per_member_serving():
         np.testing.assert_allclose(
             batched[rid], solo, rtol=0, atol=1e-6
         )
+
+
+# --- the CLI's clean-serve contract ---------------------------------------------
+
+
+@pytest.mark.parametrize("fails", [1, 5])
+def test_smoke_cli_fails_on_unclean_request(
+    fails, cache_dir, tmp_path, monkeypatch
+):
+    """Outside --chaos, ``serve_sim --smoke`` exits with an error if any
+    request was only served after a retry (``fails=1``) or after the
+    bucket fell down the ladder to another regime (``fails=5``): a
+    fallback must never pass for a kernel that works."""
+    from repro.launch import serve_sim
+
+    run_batch = SimServer._run_batch
+    left = {"n": fails}
+
+    def flaky(self, key, reqs, strategy):
+        if strategy == "swc" and left["n"] > 0:
+            left["n"] -= 1
+            raise RuntimeError("injected swc launch failure")
+        return run_batch(self, key, reqs, strategy)
+
+    monkeypatch.setattr(SimServer, "_run_batch", flaky)
+    monkeypatch.setattr(serve_sim, "use_compile_cache", lambda: None)
+    monkeypatch.setattr(
+        serve_sim.RetryPolicy, "backoff", lambda self, attempt: 0.0
+    )
+    monkeypatch.setattr("sys.argv", [
+        "serve_sim", "--smoke", "--requests", "2", "--steps", "2",
+        "--json", str(tmp_path / "serve.json"),
+    ])
+    with pytest.raises(AssertionError, match="not served cleanly"):
+        serve_sim.main()
+    left["n"] = 0
+    serve_sim.main()  # the same run with no failure passes
