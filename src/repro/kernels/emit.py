@@ -53,6 +53,11 @@ coefficient) sequences come from the generated Fornberg weights in
 ``repro.core.stencil`` (any even accuracy order — the order is a plan
 axis, ``StencilPlan.accuracy``, joining the strategy id as ``:o{A}``),
 so no kernel body hardwires a stencil order. See docs/stencils.md.
+
+Every launch is named after its kernel body (``stencil_pipelined``,
+``stencil_temporal``, ``stencil_tc``, ``stencil_stream``): the name
+becomes the custom call's HLO instruction name, which is what a device
+profile shows for the launch.
 """
 from __future__ import annotations
 
@@ -581,17 +586,20 @@ def fused_stencil_pallas(
         operands.append(aux)
     tc = plan.strategy == "tc"
     if plan.fuse_steps > 1:
+        name = "stencil_temporal"
         kernel = functools.partial(
             _kernel_temporal, ops=ops, radii=radii, tile=tile,
             phis=phis, n_f=plan.n_f, has_aux=aux is not None,
             derivs_fn=_block_derivs_tc if tc else _block_derivs,
         )
     elif tc:
+        name = "stencil_tc"
         kernel = functools.partial(
             _kernel_tc, ops=ops, radii=radii, tile=tile,
             phi=phis[0], has_aux=aux is not None,
         )
     else:
+        name = "stencil_pipelined"
         kernel = functools.partial(
             _kernel_pipelined, ops=ops, radii=radii, tile=tile,
             phi=phis[0], unroll=plan.unroll, has_aux=aux is not None,
@@ -606,6 +614,7 @@ def fused_stencil_pallas(
         ),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name=name,
     )(*operands)
 
 
@@ -752,4 +761,5 @@ def _fused_stream(
         ],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="stencil_stream",
     )(f_padded)

@@ -132,4 +132,5 @@ def xcorr1d_pallas(
         out_specs=pl.BlockSpec((block_size,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), f_padded.dtype),
         interpret=interpret,
+        name="stencil_1d",
     )(f_padded, g.astype(f_padded.dtype))

@@ -32,6 +32,12 @@ the batched fused-stencil engine:
 * ``repro.ft.faults`` — the seeded deterministic fault-injection layer
   (``SimServer(faults=...)``); ``--chaos`` drives the standard seeded
   fault plan through a live serve and asserts the recovery contract.
+* Spans — ``serve.drain`` / ``serve.batch`` / ``serve.stack`` /
+  ``serve.warm`` / ``serve.dispatch`` / ``serve.device_wait`` /
+  ``serve.fetch`` / ``serve.validate`` are ``jax.profiler``
+  ``TraceAnnotation`` spans: they record into a profiler session opened
+  around ``serve`` (one clock with the device's ops) and cost next to
+  nothing outside one. See docs/serving.md, "Tracing a server".
 
 Run:  PYTHONPATH=src python -m repro.launch.serve_sim --smoke
 
@@ -56,6 +62,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.compile_cache import use_compile_cache
 from repro.core.fusion import FusedStencilOp, integrate
@@ -81,6 +88,9 @@ DEGRADATION_LADDER = ("tc", "swc_stream", "swc", "hwc")
 # stays quarantined; degraded beats retried beats ok.
 _SEVERITY = {"ok": 0, "retried": 1, "degraded": 2, "quarantined": 3}
 
+# The one clock batch timings read (tests substitute a fake).
+_clock = time.perf_counter
+
 
 @dataclasses.dataclass(frozen=True)
 class SimRequest:
@@ -98,6 +108,11 @@ class SimRequest:
             str(self.f0.dtype),
             int(self.n_steps),
         )
+
+
+def _bucket_label(key: BucketKey) -> str:
+    """``32x64/float32/n8``: a bucket key as reports and spans show it."""
+    return "x".join(map(str, key[0])) + f"/{key[1]}/n{key[2]}"
 
 
 class RequestQueue:
@@ -190,9 +205,11 @@ class RetryPolicy:
 
 @dataclasses.dataclass
 class BatchReport:
-    """One executed batch: bucket, members, the timing the straggler
-    monitor saw, and the failure-domain outcome (strategy actually
-    used, retries consumed, per-request status)."""
+    """One executed batch: bucket, members, ``seconds`` from stacking
+    to validation (the ``serve.batch`` extent, retries included: what
+    its clients waited for, and what the straggler monitor saw), and
+    the failure-domain outcome (strategy actually used, retries
+    consumed, per-request status)."""
 
     index: int
     key: BucketKey
@@ -221,11 +238,11 @@ class SimServer:
     ``error_reports`` (quarantined requests only). A quarantine costs
     exactly the poisoned request: everyone else in its batch completes.
 
-    ``batch_hook(index, requests)`` runs inside the timed region — the
-    legacy fault-injection seam kept for straggler tests; structured
-    injection goes through ``faults`` (a
+    ``batch_hook(index, requests)`` runs inside the timed batch, before
+    dispatch — the legacy fault-injection seam kept for straggler
+    tests; structured injection goes through ``faults`` (a
     :class:`repro.ft.faults.FaultInjector`), whose batch faults fire
-    inside the same timed try block.
+    at the same point.
     """
 
     def __init__(
@@ -258,6 +275,9 @@ class SimServer:
         self.error_reports: dict[int, dict] = {}
         self._ops: dict[tuple, FusedStencilOp] = {}
         self._warmed: set = set()
+        # Tries of the batch being served, for the dispatch span: kept
+        # off ``_run_batch``'s arguments, which fault-planting tests wrap.
+        self._attempt = 0
         # Current degradation rung per bucket (absent = configured
         # strategy). Written when a batch only completes after
         # degrading; cleared when a quarantine re-attributes the
@@ -283,11 +303,12 @@ class SimServer:
         for every request that completed (quarantined requests are
         reported in ``error_reports`` instead)."""
         results: dict[int, np.ndarray] = {}
-        while queue:
-            key, reqs = queue.next_bucket(
-                lambda r: r.bucket_key, self.max_batch
-            )
-            self._serve_batch(key, reqs, results)
+        with TraceAnnotation("serve.drain", requests=len(queue)):
+            while queue:
+                key, reqs = queue.next_bucket(
+                    lambda r: r.bucket_key, self.max_batch
+                )
+                self._serve_batch(key, reqs, results)
         return results
 
     # -- failure-domain core ------------------------------------------------
@@ -296,84 +317,90 @@ class SimServer:
         self, key: BucketKey, reqs: list, results: dict
     ) -> None:
         """Serve one plan-compatible batch through the retry →
-        degrade → bisect → quarantine ladder."""
+        degrade → bisect → quarantine ladder, inside one ``serve.batch``
+        span; its report's ``seconds`` is that span's extent."""
         bucket = (key[0], key[1])
         strategy = self._strategy_for.get(bucket, self.strategy)
-        retries = 0
-        while True:
-            try:
-                out, dt = self._run_batch(key, reqs, strategy)
-                break
-            except (KeyboardInterrupt, SystemExit):
-                raise
-            except Exception as e:
-                last_err = e
-                log.warning(
-                    "batch of %d over %s failed under %s: %s: %s",
-                    len(reqs), bucket, strategy, type(e).__name__, e,
-                )
-                if retries < self.retry.max_retries:
-                    retries += 1
-                    pause = self.retry.backoff(retries)
-                    if pause:
-                        time.sleep(pause)
-                    continue
-                nxt = self._next_viable(strategy, key)
-                if nxt is not None:
+        with TraceAnnotation(
+            "serve.batch", bucket=_bucket_label(key), members=len(reqs),
+            req_ids=[r.req_id for r in reqs], strategy=strategy,
+        ):
+            t0 = _clock()
+            retries = self._attempt = 0
+            while True:
+                try:
+                    out, bad = self._run_batch(key, reqs, strategy)
+                    break
+                except (KeyboardInterrupt, SystemExit):
+                    raise
+                except Exception as e:
+                    last_err = e
+                    self._attempt += 1
                     log.warning(
-                        "degrading bucket %s: %s -> %s", bucket,
-                        strategy, nxt,
+                        "batch of %d over %s failed under %s: %s: %s",
+                        len(reqs), bucket, strategy, type(e).__name__, e,
                     )
-                    strategy = nxt
-                    self._strategy_for[bucket] = nxt
-                    retries = 0
-                    continue
-                if len(reqs) > 1:
-                    # Ladder exhausted: a member is poisoning the
-                    # batch. Bisect to isolate it — healthy halves
-                    # complete, the poison ends up in a singleton.
-                    mid = len(reqs) // 2
-                    log.warning(
-                        "bisecting failing batch of %d over %s",
-                        len(reqs), bucket,
-                    )
-                    self._serve_batch(key, reqs[:mid], results)
-                    self._serve_batch(key, reqs[mid:], results)
+                    if retries < self.retry.max_retries:
+                        retries += 1
+                        pause = self.retry.backoff(retries)
+                        if pause:
+                            time.sleep(pause)
+                        continue
+                    nxt = self._next_viable(strategy, key)
+                    if nxt is not None:
+                        log.warning(
+                            "degrading bucket %s: %s -> %s", bucket,
+                            strategy, nxt,
+                        )
+                        strategy = nxt
+                        self._strategy_for[bucket] = nxt
+                        retries = 0
+                        continue
+                    if len(reqs) > 1:
+                        # Ladder exhausted: a member is poisoning the
+                        # batch. Bisect to isolate it — healthy halves
+                        # complete, the poison ends up in a singleton.
+                        mid = len(reqs) // 2
+                        log.warning(
+                            "bisecting failing batch of %d over %s",
+                            len(reqs), bucket,
+                        )
+                        self._serve_batch(key, reqs[:mid], results)
+                        self._serve_batch(key, reqs[mid:], results)
+                        return
+                    self._quarantine(key, reqs[0], last_err, strategy)
+                    # The fault was request-attributable: later batches
+                    # of this bucket restart at the configured strategy.
+                    self._strategy_for.pop(bucket, None)
+                    self.reports.append(BatchReport(
+                        index=len(self.reports), key=key, batch=1,
+                        seconds=_clock() - t0, straggler=False,
+                        strategy=strategy, retries=retries,
+                        statuses={reqs[0].req_id: "quarantined"},
+                    ))
                     return
-                self._quarantine(key, reqs[0], last_err, strategy)
-                # The fault was request-attributable: later batches of
-                # this bucket restart at the configured strategy.
-                self._strategy_for.pop(bucket, None)
-                self.reports.append(BatchReport(
-                    index=len(self.reports), key=key, batch=1,
-                    seconds=0.0, straggler=False, strategy=strategy,
-                    retries=retries,
-                    statuses={reqs[0].req_id: "quarantined"},
-                ))
-                return
 
-        # Success: validate member outputs, then hand results back.
-        base = "ok"
-        if strategy != self.strategy:
-            base = "degraded"
-        elif retries:
-            base = "retried"
-        bad = (
-            self._nonfinite_members(out) if self.validate_output else ()
-        )
-        statuses: dict[int, str] = {}
-        for member, req in enumerate(reqs):
-            if member in bad:
-                self._quarantine(
-                    key, req,
-                    ValueError("non-finite output (NaN/inf)"),
-                    strategy,
-                )
-                statuses[req.req_id] = "quarantined"
-            else:
-                results[req.req_id] = np.asarray(out[member])
-                statuses[req.req_id] = base
-                self._mark(req.req_id, base)
+            # Success: quarantine the non-finite members, hand the
+            # rest back.
+            base = "ok"
+            if strategy != self.strategy:
+                base = "degraded"
+            elif retries:
+                base = "retried"
+            statuses: dict[int, str] = {}
+            for member, req in enumerate(reqs):
+                if member in bad:
+                    self._quarantine(
+                        key, req,
+                        ValueError("non-finite output (NaN/inf)"),
+                        strategy,
+                    )
+                    statuses[req.req_id] = "quarantined"
+                else:
+                    results[req.req_id] = np.asarray(out[member])
+                    statuses[req.req_id] = base
+                    self._mark(req.req_id, base)
+            dt = _clock() - t0
         index = len(self.reports)
         flagged = self.straggler.record(index, dt)
         self.reports.append(BatchReport(
@@ -383,11 +410,14 @@ class SimServer:
         ))
 
     def _run_batch(self, key: BucketKey, reqs: list, strategy: str):
-        """One batched integrate under ``strategy``: warm the tuning
-        cache if needed, fire injected batch faults inside the timed
-        region, and return ``(output array, seconds)``."""
+        """One try of a batch under ``strategy``: stack the members,
+        warm the tuning cache if needed, fire injected batch faults,
+        dispatch the batched integrate, wait, copy the result back to
+        the host and validate it, each phase in its own span. Returns
+        ``(output stack, indices of non-finite members)``."""
         op = self._op_for(key, strategy)
-        fb = jnp.stack([r.f0 for r in reqs])  # (B, n_f, *spatial)
+        with TraceAnnotation("serve.stack"):
+            fb = jnp.stack([r.f0 for r in reqs])  # (B, n_f, *spatial)
         warm_key = (key[0], key[1], len(reqs), strategy)
         if (
             (self.block == "auto" or strategy == "auto")
@@ -398,21 +428,28 @@ class SimServer:
             # runs the rank-then-measure search and persists the
             # measured :b{B} record; under integrate's scan tracing
             # it could only have written a cost-model record.
-            jax.block_until_ready(op(fb))
+            with TraceAnnotation("serve.warm"):
+                jax.block_until_ready(op(fb))
             self._warmed.add(warm_key)
         index = len(self.reports)
         req_ids = [r.req_id for r in reqs]
-        t0 = time.perf_counter()
         if self.batch_hook is not None:
             self.batch_hook(index, reqs)
         if self.faults is not None:
             self.faults.on_batch(index, req_ids, strategy)
-        out = jax.block_until_ready(integrate(op, fb, key[2]))
-        dt = time.perf_counter() - t0
-        out = np.asarray(out)
+        with TraceAnnotation("serve.dispatch", attempt=self._attempt):
+            out = integrate(op, fb, key[2])
+        with TraceAnnotation("serve.device_wait"):
+            out = jax.block_until_ready(out)
+        with TraceAnnotation("serve.fetch"):
+            out = np.asarray(out)
         if self.faults is not None:
             out = self.faults.corrupt_output(req_ids, out)
-        return out, dt
+        bad: set[int] = set()
+        if self.validate_output:
+            with TraceAnnotation("serve.validate"):
+                bad = self._nonfinite_members(out)
+        return out, bad
 
     def _next_viable(self, strategy: str, key: BucketKey) -> str | None:
         """First rung below ``strategy`` whose op actually builds for
@@ -461,8 +498,7 @@ class SimServer:
         self._mark(req.req_id, "quarantined")
         self.error_reports[req.req_id] = {
             "req_id": req.req_id,
-            "bucket": "x".join(map(str, key[0]))
-            + f"/{key[1]}/n{key[2]}",
+            "bucket": _bucket_label(key),
             "strategy": strategy,
             "error": f"{type(err).__name__}: {err}",
         }

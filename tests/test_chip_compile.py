@@ -7,10 +7,12 @@ chip's compiler would refuse (unaligned block shapes, DMA slices, more
 scoped VMEM than allowed) fails here. ``jax.default_backend()`` still
 reports the CPU, so every kernel is built with ``interpret=False``
 explicitly. Each test asserts the compiled program holds a
-``tpu_custom_call``. Nothing runs: results and times come only from a
-chip run.
+``tpu_custom_call``, named after its kernel body (the name a device
+profile shows for the launch). Nothing runs: results and times come
+only from a chip run.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from chip_smoke import DIFF_N, MHD_BLOCK, MHD_N, SERVE_BATCH, SERVE_SHAPES
 from repro.kernels.emit import fused_stencil_pallas
 from repro.kernels.plan import plan_stencil
+from repro.kernels.stencil1d import xcorr1d_pallas
 from repro.physics.diffusion import DiffusionProblem
 from repro.physics.mhd import MHDSolver, mhd_rhs_phi
 
@@ -95,15 +98,63 @@ def _case(name):
     return ops, phi, shape, 1, {"strategy": strategy, "fuse_steps": depth}
 
 
-@pytest.mark.parametrize("name", [
-    "swc_3d", "swc_3d_depth2", "swc_stream_3d", "tc_3d", "mhd_rhs",
-    "serve_batched_2d", "swc_1d",
-])
-def test_kernel_compiles_for_v5e(name, one_chip, x32):
-    ops, phi, shape, n_out, kw = _case(name)
-    plan = plan_stencil(ops, shape, n_out, **kw)
-    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
-    compiled = jax.jit(
-        lambda f: fused_stencil_pallas(f, ops, phi, plan, interpret=False)
-    ).lower(x).compile()
-    assert "tpu_custom_call" in compiled.as_text(), plan.strategy_id
+@pytest.fixture(scope="module")
+def compiled_text(one_chip):
+    """``get(name)``: the HLO text of one case compiled for the
+    described chip, compiled once for the module."""
+    texts = {}
+
+    def get(name):
+        if name not in texts:
+            ops, phi, shape, n_out, kw = _case(name)
+            plan = plan_stencil(ops, shape, n_out, **kw)
+            x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+            texts[name] = jax.jit(
+                lambda f: fused_stencil_pallas(
+                    f, ops, phi, plan, interpret=False
+                )
+            ).lower(x).compile().as_text()
+        return texts[name]
+
+    return get
+
+
+# Each case and the kernel body its launch is named after.
+KERNEL_NAMES = {
+    "swc_3d": "stencil_pipelined",
+    "swc_3d_depth2": "stencil_temporal",
+    "swc_stream_3d": "stencil_stream",
+    "tc_3d": "stencil_tc",
+    "mhd_rhs": "stencil_pipelined",
+    "serve_batched_2d": "stencil_pipelined",
+    "swc_1d": "stencil_pipelined",
+}
+
+
+@pytest.mark.parametrize("name", list(KERNEL_NAMES))
+def test_kernel_compiles_for_v5e(name, compiled_text, x32):
+    assert "tpu_custom_call" in compiled_text(name), name
+
+
+@pytest.mark.parametrize("name", list(KERNEL_NAMES))
+def test_kernel_launch_carries_its_body_name(name, compiled_text, x32):
+    launches = [
+        line for line in compiled_text(name).splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    assert launches, name
+    for line in launches:
+        m = re.match(r"\s*(?:ROOT )?%([A-Za-z_]+)(?:\.\d+)? = ", line)
+        assert m and m.group(1) == KERNEL_NAMES[name], line[:120]
+
+
+def test_xcorr_1d_launch_carries_its_name(one_chip, x32):
+    """The paper's 1-D cross-correlation kernel: Mosaic refuses its
+    unaligned 1-D loads on a v5e, so its name is read from the lowered
+    module, where the compiled instruction takes it from."""
+    f = jax.ShapeDtypeStruct((LINE_N + 6,), jnp.float32, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((7,), jnp.float32, sharding=one_chip)
+    text = jax.jit(
+        lambda f, g: xcorr1d_pallas(f, g, interpret=False)
+    ).lower(f, g).as_text()
+    assert re.findall(r'kernel_name = "(\w+)"', text) == ["stencil_1d"]

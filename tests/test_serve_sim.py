@@ -1,15 +1,19 @@
 """Serving-loop tests: shape-bucketed batching (a mixed-shape queue
 drains into plan-compatible buckets, FIFO head-of-line), per-bucket
 tuning-cache behavior (first batch of a bucket tunes, later batches and
-later servers replay the persisted ``:b{B}`` record), and
-``StragglerMonitor`` engagement on an injected slow batch."""
-import time
+later servers replay the persisted ``:b{B}`` record),
+``StragglerMonitor`` engagement on an injected slow batch, and the
+server's profiler spans."""
+import glob
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro.ft.supervisor import StragglerMonitor
+from repro.launch import serve_sim
 from repro.launch.serve_sim import (
     RequestQueue,
     SimRequest,
@@ -22,6 +26,27 @@ from repro.launch.serve_sim import (
 def cache_dir(tmp_path, monkeypatch):
     monkeypatch.setenv("REPRO_TUNE_CACHE", str(tmp_path))
     return tmp_path
+
+
+class _FakeClock:
+    """A clock that moves ``tick`` seconds at every read, and by hand."""
+
+    def __init__(self, tick: float = 0.01):
+        self.now, self.tick = 0.0, tick
+
+    def __call__(self) -> float:
+        self.now += self.tick
+        return self.now
+
+    def advance(self, seconds: float) -> None:
+        self.now += seconds
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    fake = _FakeClock()
+    monkeypatch.setattr(serve_sim, "_clock", fake)
+    return fake
 
 
 def _req(rid, shape, n_steps=4, dtype=jnp.float32):
@@ -112,7 +137,7 @@ def test_per_bucket_tuning_cache_hits(cache_dir):
 # --- straggler engagement -------------------------------------------------------
 
 
-def test_straggler_monitor_flags_injected_slow_batch():
+def test_straggler_monitor_flags_injected_slow_batch(clock):
     """A deliberately slowed batch (contended-member stand-in) trips
     the trailing-median monitor once enough history exists, and the
     flag lands in the server's batch report."""
@@ -120,7 +145,7 @@ def test_straggler_monitor_flags_injected_slow_batch():
 
     def inject(index, reqs):
         if index == slow_index:
-            time.sleep(0.4)
+            clock.advance(0.4)
 
     server = SimServer(
         strategy="swc",
@@ -137,11 +162,103 @@ def test_straggler_monitor_flags_injected_slow_batch():
     assert server.straggler.flagged[0][0] == slow_index
 
 
-def test_fast_batches_do_not_flag():
+def test_fast_batches_do_not_flag(clock):
     server = SimServer(strategy="swc", max_batch=2)
     server.serve(demo_queue([(16, 32)], n_steps=2, requests=12))
     assert not any(rep.straggler for rep in server.reports)
     assert server.straggler.flagged == []
+    # One timing per batch: the clock is read at its start and its end.
+    for rep in server.reports:
+        assert rep.seconds == pytest.approx(clock.tick)
+
+
+# --- profiler spans -------------------------------------------------------------
+
+# Each span and the span it nests in.
+SPAN_PARENT = {
+    "serve.drain": None,
+    "serve.batch": "serve.drain",
+    "serve.stack": "serve.batch",
+    "serve.warm": "serve.batch",
+    "serve.dispatch": "serve.batch",
+    "serve.device_wait": "serve.batch",
+    "serve.fetch": "serve.batch",
+    "serve.validate": "serve.batch",
+}
+
+
+def _serve_profiled(server, queue, logdir):
+    """Serve under a profiler session; returns (results, serve.* spans
+    as (name, start, end, metadata))."""
+    with jax.profiler.trace(str(logdir)):
+        results = server.serve(queue)
+    (path,) = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    spans = [
+        (e.name, e.start_ns, e.start_ns + e.duration_ns, dict(list(e.stats)))
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+        if e.name.startswith("serve.")
+    ]
+    return results, spans
+
+
+def test_serve_spans_nest_and_count_batches(cache_dir, tmp_path):
+    """Every span of the serving path is recorded on the profiler's
+    host plane, nested as the server runs it, one ``serve.batch`` per
+    batch report, carrying its bucket, members and request ids."""
+    def queue():
+        return demo_queue([(16, 32), (12, 24)], n_steps=2, requests=8)
+
+    # block="auto": the first batch of each bucket runs the warm call,
+    # which here replays the records a first server measured.
+    SimServer(strategy="swc", block="auto", max_batch=2).serve(queue())
+    server = SimServer(strategy="swc", block="auto", max_batch=2)
+    ids = {r.req_id for r in queue().snapshot()}
+    results, spans = _serve_profiled(server, queue(), tmp_path / "trace")
+    assert set(results) == ids
+    assert {name for name, *_ in spans} == set(SPAN_PARENT)
+    for name, start, end, _ in spans:
+        holders = [
+            s for s in spans
+            if s[1] <= start and end <= s[2] and s[:3] != (name, start, end)
+        ]
+        parent = min(holders, key=lambda s: s[2] - s[1])[0] if holders else None
+        assert parent == SPAN_PARENT[name], (name, parent)
+    batches = [meta for name, *_, meta in spans if name == "serve.batch"]
+    assert len(batches) == len(server.reports) == 4
+    assert [b["members"] for b in batches] == [
+        rep.batch for rep in server.reports
+    ] == [2] * 4
+    assert {b["bucket"] for b in batches} == {
+        "16x32/float32/n2", "12x24/float32/n2"
+    }
+    assert all(b["strategy"] == "swc" for b in batches)
+    served = sorted(
+        int(i) for b in batches for i in str(b["req_ids"]).strip("[]").split(",")
+    )
+    assert served == sorted(ids)
+    drains = [meta for name, *_, meta in spans if name == "serve.drain"]
+    assert [d["requests"] for d in drains] == [8]
+    attempts = [meta["attempt"] for name, *_, meta in spans if name == "serve.dispatch"]
+    assert attempts == [0] * 4
+
+
+def test_profiler_session_leaves_results_bit_identical(tmp_path):
+    """Spans only record: the same queue served with and without a
+    profiler session gives the same bits."""
+    def queue():
+        return demo_queue([(16, 32), (12, 24)], n_steps=3, requests=6, seed=5)
+
+    plain = SimServer(strategy="swc", max_batch=2).serve(queue())
+    traced, spans = _serve_profiled(
+        SimServer(strategy="swc", max_batch=2), queue(), tmp_path / "trace"
+    )
+    assert spans
+    assert sorted(plain) == sorted(traced)
+    for rid, out in plain.items():
+        np.testing.assert_array_equal(out, traced[rid])
 
 
 # --- batched numerics through the server ----------------------------------------
