@@ -15,7 +15,9 @@ the batched fused-stencil engine:
   ``StencilPlan``). Ops are cached per bucket and ``block="auto"``
   resolves through the persistent tuning cache, so the first batch of
   a bucket warms the ``:b{B}``-keyed record and every later batch
-  replays it.
+  replays it. The batched ``integrate`` is jitted once per (bucket,
+  strategy) and compiled once per batch extent, so later batches
+  dispatch a cached executable.
 * **Failure domains** — one poisoned request must cost one request,
   never the queue. Every batch runs under a :class:`RetryPolicy`:
   transient failures retry with backoff; repeated failures degrade the
@@ -227,7 +229,12 @@ class SimServer:
     One ``FusedStencilOp`` per (bucket, strategy) — built lazily,
     cached for the server's lifetime (``op_builds`` counts cache
     misses); requests are stacked member-major to (B, n_f, *spatial)
-    and integrated in one batched call per bucket.
+    and integrated in one batched call per bucket. That call is one
+    ``jax.jit`` program per (bucket, strategy), also kept for the
+    server's lifetime: jit's own shape cache holds one executable per
+    batch extent B, so a batch traces, lowers and compiles only on a
+    new bucket, B or strategy (``exe_builds`` counts those traces) and
+    every later batch is a cached dispatch.
 
     Failure domains: every batch executes inside a try/except driven
     by ``retry`` (:class:`RetryPolicy` — retry with backoff, then the
@@ -271,9 +278,11 @@ class SimServer:
         self.validate_output = validate_output
         self.reports: list[BatchReport] = []
         self.op_builds = 0
+        self.exe_builds = 0
         self.request_status: dict[int, str] = {}
         self.error_reports: dict[int, dict] = {}
         self._ops: dict[tuple, FusedStencilOp] = {}
+        self._exes: dict[tuple, Callable] = {}
         self._warmed: set = set()
         # Tries of the batch being served, for the dispatch span: kept
         # off ``_run_batch``'s arguments, which fault-planting tests wrap.
@@ -297,6 +306,27 @@ class SimServer:
             self._ops[op_key] = problem.step_op(strategy, block)
             self.op_builds += 1
         return self._ops[op_key]
+
+    def _dispatch(
+        self, key: BucketKey, strategy: str, op: FusedStencilOp, fb
+    ) -> jnp.ndarray:
+        """Run ``fb`` through the jitted batched ``integrate`` of its
+        (bucket, strategy), built on first use; B is left to jit's shape
+        cache. A program whose call raises is not kept, so the retry
+        builds a fresh one: a jit whose trace failed would trace again
+        on every later call."""
+        shape, dtype, n_steps = key
+        exe_key = (shape, dtype, strategy, n_steps)
+        exe = self._exes.pop(exe_key, None)
+        if exe is None:
+            def program(fb, op=op, n=n_steps):
+                self.exe_builds += 1  # runs once per trace, not per call
+                return integrate(op, fb, n)
+
+            exe = jax.jit(program)
+        out = exe(fb)
+        self._exes[exe_key] = exe
+        return out
 
     def serve(self, queue: RequestQueue) -> dict[int, np.ndarray]:
         """Drain the queue; returns {req_id: final (n_f, *spatial)}
@@ -412,8 +442,8 @@ class SimServer:
     def _run_batch(self, key: BucketKey, reqs: list, strategy: str):
         """One try of a batch under ``strategy``: stack the members,
         warm the tuning cache if needed, fire injected batch faults,
-        dispatch the batched integrate, wait, copy the result back to
-        the host and validate it, each phase in its own span. Returns
+        dispatch the jitted batched integrate, wait, copy the result
+        back to the host and validate it, each phase in its own span. Returns
         ``(output stack, indices of non-finite members)``."""
         op = self._op_for(key, strategy)
         with TraceAnnotation("serve.stack"):
@@ -424,10 +454,11 @@ class SimServer:
             and strategy != "hwc"
             and warm_key not in self._warmed
         ):
-            # Eager warm call OUTSIDE lax control flow: a cache miss
-            # runs the rank-then-measure search and persists the
-            # measured :b{B} record; under integrate's scan tracing
-            # it could only have written a cost-model record.
+            # Eager warm call OUTSIDE jit: a cache miss runs the
+            # rank-then-measure search and persists the measured :b{B}
+            # record, which the jitted program's trace then replays;
+            # under tracing it could only have written a cost-model
+            # record.
             with TraceAnnotation("serve.warm"):
                 jax.block_until_ready(op(fb))
             self._warmed.add(warm_key)
@@ -438,7 +469,7 @@ class SimServer:
         if self.faults is not None:
             self.faults.on_batch(index, req_ids, strategy)
         with TraceAnnotation("serve.dispatch", attempt=self._attempt):
-            out = integrate(op, fb, key[2])
+            out = self._dispatch(key, strategy, op, fb)
         with TraceAnnotation("serve.device_wait"):
             out = jax.block_until_ready(out)
         with TraceAnnotation("serve.fetch"):
@@ -749,7 +780,7 @@ def main() -> None:
     print(
         f"served {len(results)}/{args.requests} request(s) in "
         f"{len(server.reports)} batch(es) / {server.op_builds} op "
-        f"build(s), {wall:.2f}s "
+        f"build(s) / {server.exe_builds} program trace(s), {wall:.2f}s "
         f"({members * args.steps / wall:.1f} member-steps/s, "
         f"{stragglers} straggler(s), "
         + ", ".join(f"{k}={v}" for k, v in sorted(status_counts.items()))

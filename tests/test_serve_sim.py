@@ -12,12 +12,16 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
+from repro.core.fusion import integrate
+from repro.ft.faults import FaultInjector, FaultSpec
 from repro.ft.supervisor import StragglerMonitor
 from repro.launch import serve_sim
 from repro.launch.serve_sim import (
     RequestQueue,
+    RetryPolicy,
     SimRequest,
     SimServer,
+    _vmap_reference,
     demo_queue,
 )
 
@@ -52,6 +56,18 @@ def clock(monkeypatch):
 def _req(rid, shape, n_steps=4, dtype=jnp.float32):
     f0 = jnp.zeros((1,) + shape, dtype) + 1e-5 * (rid + 1)
     return SimRequest(rid, f0, n_steps)
+
+
+def _served_stacks(reports, reqs):
+    """{bucket key: [(req ids, member stack)]} of the batches that
+    ``reports`` record for ``reqs``, members in batch order."""
+    by_id = {r.req_id: r for r in reqs}
+    stacks = {}
+    for rep in reports:
+        ids = list(rep.statuses)
+        fb = jnp.stack([by_id[rid].f0 for rid in ids])
+        stacks.setdefault(rep.key, []).append((ids, fb))
+    return stacks
 
 
 # --- queue bucketing ------------------------------------------------------------
@@ -132,6 +148,144 @@ def test_per_bucket_tuning_cache_hits(cache_dir):
     fresh.serve(demo_queue([(16, 32), (12, 24)], n_steps=2, requests=8))
     assert sess_mod.MEASURE_COUNT == measured  # pure cache replay
     assert set(TuningCache().items()) == keys
+
+
+def test_auto_block_record_is_measured_before_the_program_traces(
+    cache_dir, monkeypatch
+):
+    """block="auto" under the jitted batch program: the eager
+    ``serve.warm`` call measures and persists the bucket's ``:b{B}``
+    record before the program of that (bucket, B) traces, so the trace
+    replays a measured record and measures nothing itself; a fresh
+    server replays it with no new measurement and returns what the
+    eager ``integrate`` returns."""
+    from repro.tuning import TuningCache
+    from repro.tuning import session as sess_mod
+
+    traced = []
+
+    def spy(op, fb, n):
+        before = sess_mod.MEASURE_COUNT
+        records = {
+            k: rec.source for k, rec in TuningCache().items().items()
+            if f":b{fb.shape[0]}:" in k
+            and f"|{'x'.join(map(str, fb.shape[2:]))}|" in k
+        }
+        out = integrate(op, fb, n)
+        traced.append((fb.shape, records, sess_mod.MEASURE_COUNT - before))
+        return out
+
+    monkeypatch.setattr(serve_sim, "integrate", spy)
+
+    def queue():
+        return demo_queue([(16, 32), (12, 24)], n_steps=2, requests=8)
+
+    server = SimServer(strategy="swc", block="auto", max_batch=2)
+    server.serve(queue())
+    measured = sess_mod.MEASURE_COUNT
+    assert measured > 0
+    assert server.exe_builds == len(traced) == 2
+    for shape, records, new in traced:
+        assert records and set(records.values()) == {"measured"}, (shape, records)
+        assert new == 0, shape
+
+    fresh = SimServer(strategy="swc", block="auto", max_batch=2)
+    q = queue()
+    reqs = q.snapshot()
+    results = fresh.serve(q)
+    assert sess_mod.MEASURE_COUNT == measured
+    assert fresh.exe_builds == 2
+    for key, stacks in _served_stacks(fresh.reports, reqs).items():
+        op = fresh._op_for(key, "swc")
+        for ids, fb in stacks:
+            eager = np.asarray(integrate(op, fb, key[2]))
+            for member, rid in enumerate(ids):
+                np.testing.assert_array_equal(results[rid], eager[member])
+
+
+# --- the jitted batch program -------------------------------------------------
+
+
+def test_batch_program_compiles_once_per_bucket_and_batch_size():
+    """Rounds of a two-bucket queue at fixed batch sizes (3 and 2 per
+    bucket): one program trace per distinct (bucket, B, strategy) in
+    the first round, none after, and every batch's result is the eager
+    ``integrate`` of the same stack, bit for bit."""
+    server = SimServer(strategy="swc", max_batch=3)
+    for rnd in range(3):
+        queue = demo_queue([(16, 32), (12, 24)], n_steps=3, requests=10, seed=rnd)
+        reqs = queue.snapshot()
+        first = len(server.reports)
+        results = server.serve(queue)
+        assert [rep.batch for rep in server.reports[first:]] == [3, 3, 2, 2]
+        assert server.exe_builds == 4  # 2 buckets x B in {3, 2}, swc
+        assert server.op_builds == 2
+        for key, stacks in _served_stacks(server.reports[first:], reqs).items():
+            op = server._op_for(key, "swc")
+            for ids, fb in stacks:
+                eager = np.asarray(integrate(op, fb, key[2]))
+                for member, rid in enumerate(ids):
+                    np.testing.assert_array_equal(results[rid], eager[member])
+
+
+def test_degraded_rung_adds_one_program():
+    """A fault planted on the first rung degrades the bucket to the next
+    one, which traces exactly one program of its own; later batches of
+    the bucket reuse it."""
+    server = SimServer(
+        strategy="swc", max_batch=2, retry=RetryPolicy(backoff_s=0.0)
+    )
+    server.serve(demo_queue([(16, 32)], n_steps=2, requests=4))
+    assert server.exe_builds == 1
+    server.faults = FaultInjector([
+        FaultSpec("serve.batch", "compile", strategy="swc",
+                  times=server.retry.max_retries + 1),
+    ])
+    queue = demo_queue([(16, 32)], n_steps=2, requests=6, seed=1)
+    reqs = queue.snapshot()
+    results = server.serve(queue)
+    assert server.exe_builds == 2
+    assert [rep.strategy for rep in server.reports[-3:]] == ["hwc"] * 3
+    assert {server.request_status[r.req_id] for r in reqs} == {"degraded"}
+    expect = np.asarray(_vmap_reference(server, reqs))
+    got = np.stack([results[r.req_id] for r in reqs])
+    np.testing.assert_allclose(got, expect, rtol=0, atol=1e-6)
+
+
+def test_failed_trace_is_not_cached(monkeypatch):
+    """A batch program whose trace raises is not kept: the retry traces
+    a fresh one, which then serves every later batch of the bucket with
+    no further trace (a jit whose trace failed traces on every call)."""
+    fail = {"n": 0}
+
+    def flaky(op, fb, n):
+        if fail["n"]:
+            fail["n"] -= 1
+            raise RuntimeError("injected lowering failure")
+        return integrate(op, fb, n)
+
+    monkeypatch.setattr(serve_sim, "integrate", flaky)
+    server = SimServer(
+        strategy="swc", max_batch=2, retry=RetryPolicy(backoff_s=0.0)
+    )
+
+    def serve(requests):
+        first = len(server.reports)
+        queue = demo_queue([(16, 32)], n_steps=2, requests=requests)
+        return server.serve(queue), server.reports[first:]
+
+    serve(2)
+    assert server.exe_builds == 1  # B=2
+    fail["n"] = 1
+    results, reports = serve(3)  # B=2 cached; B=1's first trace raises
+    assert sorted(results) == [0, 1, 2]
+    assert [(rep.batch, rep.retries) for rep in reports] == [(2, 0), (1, 1)]
+    assert reports[1].statuses == {2: "retried"}
+    assert server.exe_builds == 3  # the failed trace and its retry
+    serve(3)
+    assert server.exe_builds == 4  # the fresh program traces B=2 once
+    serve(3)
+    assert server.exe_builds == 4
 
 
 # --- straggler engagement -------------------------------------------------------
