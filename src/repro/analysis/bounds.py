@@ -87,10 +87,12 @@ def _sweep_exts(plan: StencilPlan) -> list[tuple[int, ...]]:
 def _audit_pipelined(
     plan: StencilPlan, ops: Any, findings: list[Finding],
     observed: list[tuple[int, ...]],
+    aux_rows: tuple[int, ...] | None = None,
 ) -> int:
     """Shadow-run the pipelined/temporal/tc body once (it is grid-
     position independent; placement is proved arithmetically) and
-    return the measured VMEM bytes."""
+    return the measured VMEM bytes. ``aux_rows`` splits the plan's aux
+    rows over that many operands (default: one stacked operand)."""
     from repro.kernels import emit
 
     sid = plan.strategy_id
@@ -120,12 +122,19 @@ def _audit_pipelined(
         "f", (plan.n_f,) + window, plan.dtype, initialized=True
     )
     o_ref = ShadowRef("o", (plan.n_out,) + out_tile, plan.dtype)
-    rest: list[ShadowRef] = []
-    if plan.n_aux:
-        rest.append(ShadowRef(
-            "aux", (plan.n_aux,) + aux_window, plan.dtype,
-            initialized=True,
-        ))
+    if aux_rows is None:
+        aux_rows = (plan.n_aux,) if plan.n_aux else ()
+    if sum(aux_rows) != plan.n_aux:
+        raise AuditError(
+            "bounds", f"aux operands of {aux_rows} rows for n_aux "
+            f"{plan.n_aux}",
+        )
+    rest: list[ShadowRef] = [
+        ShadowRef(
+            f"aux{i}", (rows,) + aux_window, plan.dtype, initialized=True,
+        )
+        for i, rows in enumerate(aux_rows)
+    ]
     phis = make_synthetic_phis(
         plan,
         _sweep_exts(plan) if plan.fuse_steps > 1 else [plan.block],
@@ -145,13 +154,13 @@ def _audit_pipelined(
                 emit._kernel_temporal(
                     f_ref, *rest, o_ref, ops=ops, radii=plan.radii,
                     tile=plan.block, phis=phis, n_f=plan.n_f,
-                    has_aux=bool(plan.n_aux), derivs_fn=derivs_fn,
+                    n_aux_refs=len(rest), derivs_fn=derivs_fn,
                 )
             else:
                 emit._kernel_pipelined(
                     f_ref, *rest, o_ref, ops=ops, radii=plan.radii,
                     tile=plan.block, phi=phis[0],
-                    unroll=plan.unroll, has_aux=bool(plan.n_aux),
+                    unroll=plan.unroll, n_aux_refs=len(rest),
                     derivs_fn=derivs_fn,
                 )
     except AuditError as e:
@@ -355,12 +364,16 @@ def _audit_stream(
     )
 
 
-def audit_plan(plan: StencilPlan, ops: Any) -> PlanAudit:
+def audit_plan(
+    plan: StencilPlan, ops: Any, aux_rows: tuple[int, ...] | None = None
+) -> PlanAudit:
     """Run the full bounds/coverage/uninit/geometry audit for one plan.
 
     Batched plans are audited through the batch=1 plan the launch
     actually lowers (member-major field scaling), reported under the
     ORIGINAL strategy id so findings name the user-facing plan.
+    ``aux_rows`` audits an unbatched pipelined plan with its aux rows
+    split over several operands, as a tuple ``aux`` launches it.
     """
     sid = plan.strategy_id
     exec_plan = _derived_exec_plan(plan)
@@ -371,7 +384,7 @@ def audit_plan(plan: StencilPlan, ops: Any) -> PlanAudit:
             measured = _audit_stream(exec_plan, ops, findings, observed)
         else:
             measured = _audit_pipelined(
-                exec_plan, ops, findings, observed
+                exec_plan, ops, findings, observed, aux_rows
             )
     except AuditError as e:  # geometry failures outside the body run
         findings.append(Finding(e.cls, sid, e.detail))

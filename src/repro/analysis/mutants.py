@@ -81,7 +81,7 @@ def _temporal_sweeps_skewed(cur, ops, radii, tile, phis, derivs_fn=None):
 
 
 def _kernel_pipelined_gap(
-    f_ref, *rest, ops, radii, tile, phi, unroll, has_aux,
+    f_ref, *rest, ops, radii, tile, phi, unroll, n_aux_refs,
     derivs_fn=None,
 ):
     """_kernel_pipelined that never computes the LAST unroll sub-tile
@@ -89,15 +89,15 @@ def _kernel_pipelined_gap(
     from repro.kernels import emit
 
     derivs_fn = derivs_fn or emit._block_derivs
-    aux_ref, o_ref = rest if has_aux else (None, rest[0])
+    aux_refs, o_ref = rest[:n_aux_refs], rest[n_aux_refs]
     fblk = f_ref[...]
     tx = tile[-1]
     rx = radii[-1]
     for e in range(max(unroll - 1, 1) if unroll > 1 else unroll):
         sub = fblk if unroll == 1 else fblk[..., e * tx : e * tx + tx + 2 * rx]
         derivs = derivs_fn(sub, ops, radii, tile)
-        if has_aux:
-            ablk = aux_ref[...]
+        if aux_refs:
+            ablk = emit._join_rows([r[...] for r in aux_refs])
             a_sub = ablk if unroll == 1 else ablk[..., e * tx : (e + 1) * tx]
             val = phi(derivs, a_sub)
         else:

@@ -205,6 +205,17 @@ class ShadowArray:
         shape[1 + axis] = ext_out
         return ShadowArray(tuple(shape), np.float32)
 
+    def shadow_join(self, blocks: Sequence["ShadowArray"]) -> "ShadowArray":
+        """Shadow of ``emit._join_rows``: blocks joined along the row
+        axis must agree on every other extent."""
+        rest = {tuple(b.shape[1:]) for b in blocks}
+        if len(rest) != 1:
+            raise AuditError(
+                "bounds", f"aux blocks of extents {sorted(rest)} joined"
+            )
+        rows = sum(b.shape[0] for b in blocks)
+        return ShadowArray((rows,) + rest.pop(), self.dtype)
+
     def __repr__(self) -> str:
         return f"ShadowArray(shape={self.shape}, dtype={self.dtype})"
 

@@ -304,7 +304,7 @@ class FusedStencilOp:
     # -- single device ------------------------------------------------------
 
     def resolved(
-        self, f: jnp.ndarray, aux: jnp.ndarray | None = None
+        self, f: jnp.ndarray, aux: kref.Aux = None
     ) -> "FusedStencilOp":
         """An equivalent, fully concrete op — the resolution contract.
 
@@ -319,6 +319,10 @@ class FusedStencilOp:
         jit tracing — so the returned op is bit-identical across a
         cold-measure → cache-write → warm-hit cycle.
         """
+        if not self.needs_resolution:
+            return self
+        # The tuner measures with the aux rows as one array.
+        aux = kref.join_aux(aux, 1 if f.ndim == self.ops.ndim + 2 else 0)
         if self.strategy == "auto":
             from repro.tuning.session import auto_strategy_nd
 
@@ -330,8 +334,6 @@ class FusedStencilOp:
                 self, strategy=strategy, block=tuple(block),
                 fuse_steps=int(depth),
             )
-        if self.fuse_steps != "auto":
-            return self
         from repro.tuning.session import auto_fuse_nd
 
         block, depth = auto_fuse_nd(
@@ -343,15 +345,19 @@ class FusedStencilOp:
         )
 
     def apply_padded(
-        self, f_padded: jnp.ndarray, aux: jnp.ndarray | None = None
+        self, f_padded: jnp.ndarray, aux: kref.Aux = None
     ) -> jnp.ndarray:
         """Apply to an already-padded field stack (ghost cells present:
         ``radius * fuse_steps`` per axis — one radius per fused sweep).
 
         ``aux``: extra point-wise inputs forwarded to φ (fused axpy /
-        RK carries — beyond-paper extension); (n_aux, *interior) at
-        depth 1, padded by ``radius * (fuse_steps - 1)`` at depth > 1 so
-        intermediate sweeps see an aligned carry.
+        RK carries, a leapfrog's previous level and coefficient fields
+        — beyond-paper extension); (n_aux, *interior) at depth 1, padded
+        by ``radius * (fuse_steps - 1)`` at depth > 1 so intermediate
+        sweeps see an aligned carry. One array, or a tuple of arrays
+        whose rows together are φ's aux rows: the Pallas regimes stage
+        each as its own kernel operand, so arrays that live apart are
+        not stacked in HBM first.
 
         A batched (batch, n_f, *padded) ensemble stack is accepted
         wherever an (n_f, *padded) stack is — detected by rank and
@@ -390,7 +396,7 @@ class FusedStencilOp:
         )
 
     def __call__(
-        self, f: jnp.ndarray, aux: jnp.ndarray | None = None
+        self, f: jnp.ndarray, aux: kref.Aux = None
     ) -> jnp.ndarray:
         """ψ then φ(A·B): pad with the boundary function and apply —
         advancing ``fuse_steps`` time steps per call.
@@ -410,9 +416,12 @@ class FusedStencilOp:
             spatial_axes=range(lead, f.ndim),
         )
         if aux is not None and depth > 1:
-            aux = boundary.pad(
-                aux, [r * (depth - 1) for r in rads], modes,
-                spatial_axes=range(lead, aux.ndim),
+            aux = jax.tree.map(
+                lambda a: boundary.pad(
+                    a, [r * (depth - 1) for r in rads], modes,
+                    spatial_axes=range(lead, a.ndim),
+                ),
+                aux,
             )
         out = self.apply_padded(fp, aux=aux)
         if self.boundary_weights and any(m != "periodic" for m in modes):
@@ -423,7 +432,7 @@ class FusedStencilOp:
         self,
         f: jnp.ndarray,
         out: jnp.ndarray,
-        aux: jnp.ndarray | None,
+        aux: kref.Aux,
         lead: int,
     ) -> jnp.ndarray:
         """Overwrite the wall-adjacent cells of the kernel output with
@@ -441,6 +450,7 @@ class FusedStencilOp:
         modes = self.boundary_modes
         rads = self.radius_per_axis
         phi = self.phi[0] if isinstance(self.phi, (tuple, list)) else self.phi
+        aux = kref.join_aux(aux, lead - 1)
 
         def bc_output(fm, auxm):
             derivs = boundary.apply_operator_set_bc(
@@ -474,7 +484,7 @@ class FusedStencilOp:
         self,
         f_local: jnp.ndarray,
         mesh_axes: Sequence[str | None],
-        aux: jnp.ndarray | None = None,
+        aux: kref.Aux = None,
         *,
         overlap: bool = False,
     ) -> jnp.ndarray:
@@ -529,6 +539,8 @@ class FusedStencilOp:
         the dependent edge slabs (with their ``radius * (fuse_steps-1)``
         aux windows) are computed from the exchanged array afterwards.
         """
+        # The exchange moves one aux array.
+        aux = kref.join_aux(aux)
         if self.needs_resolution:
             return self.resolved(f_local, aux).apply_sharded(
                 f_local, mesh_axes, aux, overlap=overlap

@@ -224,22 +224,36 @@ def _block_derivs_tc(
     return out
 
 
+def _join_rows(blocks):
+    """Aux blocks joined along the row axis, in VMEM — the one array φ
+    sees whether the plan's aux rows arrived as one operand or several.
+    Like :func:`_contract`, dispatches to the static auditor's shadow
+    arrays (``shadow_join``) so they can run the same body."""
+    if len(blocks) == 1:
+        return blocks[0]
+    shadow = getattr(blocks[0], "shadow_join", None)
+    if shadow is not None:
+        return shadow(blocks)
+    return jnp.concatenate(blocks, axis=0)
+
+
 def _kernel_pipelined(
-    f_ref, *rest, ops, radii, tile, phi, unroll, has_aux,
+    f_ref, *rest, ops, radii, tile, phi, unroll, n_aux_refs,
     derivs_fn=_block_derivs,
 ):
-    """Pipelined kernel, any rank. ``rest`` is (aux_ref, o_ref) when the
-    plan carries aux inputs, else (o_ref,). ``derivs_fn`` selects the
+    """Pipelined kernel, any rank. ``rest`` is (*aux_refs, o_ref): one
+    ref per aux operand (``n_aux_refs`` of them, 0 for an aux-free
+    plan), joined row-wise for φ. ``derivs_fn`` selects the
     tap-evaluation lowering (VPU shifted slices or MXU contractions)."""
-    aux_ref, o_ref = rest if has_aux else (None, rest[0])
+    aux_refs, o_ref = rest[:n_aux_refs], rest[n_aux_refs]
     fblk = f_ref[...]
     tx = tile[-1]
     rx = radii[-1]
     for e in range(unroll):  # static: unrolled at trace time
         sub = fblk if unroll == 1 else fblk[..., e * tx : e * tx + tx + 2 * rx]
         derivs = derivs_fn(sub, ops, radii, tile)
-        if has_aux:
-            ablk = aux_ref[...]
+        if aux_refs:
+            ablk = _join_rows([r[...] for r in aux_refs])
             a_sub = ablk if unroll == 1 else ablk[..., e * tx : (e + 1) * tx]
             val = phi(derivs, a_sub)
         else:
@@ -250,12 +264,12 @@ def _kernel_pipelined(
             o_ref[..., e * tx : (e + 1) * tx] = val
 
 
-def _kernel_tc(f_ref, *rest, ops, radii, tile, phi, has_aux):
+def _kernel_tc(f_ref, *rest, ops, radii, tile, phi, n_aux_refs):
     """Depth-1 MXU kernel: the pipelined body with banded-contraction
     tap evaluation (named so tc launches are identifiable in traces)."""
     _kernel_pipelined(
         f_ref, *rest, ops=ops, radii=radii, tile=tile, phi=phi,
-        unroll=1, has_aux=has_aux, derivs_fn=_block_derivs_tc,
+        unroll=1, n_aux_refs=n_aux_refs, derivs_fn=_block_derivs_tc,
     )
 
 
@@ -289,7 +303,7 @@ def _temporal_sweeps(
 
 
 def _kernel_temporal(
-    f_ref, *rest, ops, radii, tile, phis, n_f, has_aux,
+    f_ref, *rest, ops, radii, tile, phis, n_f, n_aux_refs,
     derivs_fn=_block_derivs,
 ):
     """Temporal-fusion kernel, any rank: apply the fused op
@@ -298,30 +312,27 @@ def _kernel_temporal(
     one radius per axis; intermediate field stacks (and carries) stay
     on-chip — only the final tile is written back to HBM.
 
-    ``rest`` is (aux_ref, o_ref) when the plan carries aux inputs, else
-    (o_ref,). The staged aux window is ``tile + 2r(S-1)`` so every
+    ``rest`` is (*aux_refs, o_ref), one ref per aux operand
+    (``n_aux_refs``, 0 for an aux-free plan), joined row-wise into one
+    carry. The staged aux window is ``tile + 2r(S-1)`` so every
     intermediate sweep sees a point-wise-aligned carry. The aux-free
     case delegates to :func:`_temporal_sweeps` (shared with the
     streaming kernel) so the sweep-shrinking arithmetic lives once.
     """
-    if not has_aux:
-        (o_ref,) = rest
+    aux_refs, o_ref = rest[:n_aux_refs], rest[n_aux_refs]
+    if not aux_refs:
         o_ref[...] = _temporal_sweeps(
             f_ref[...], ops, radii, tile, phis, derivs_fn=derivs_fn
         )
         return
-    aux_ref, o_ref = rest
     n_steps = len(phis)
     cur = f_ref[...]
     # The staged aux block may be tile-aligned past the r·(S-1)-widened
     # window (compat.element_window_spec): read the window only.
-    cur_aux = aux_ref[
-        (slice(None),)
-        + tuple(
-            slice(0, t + 2 * r * (n_steps - 1))
-            for t, r in zip(tile, radii)
-        )
-    ]
+    window = (slice(None),) + tuple(
+        slice(0, t + 2 * r * (n_steps - 1)) for t, r in zip(tile, radii)
+    )
+    cur_aux = _join_rows([r[window] for r in aux_refs])
     for s, phi in enumerate(phis):  # static: unrolled at trace time
         margin = n_steps - 1 - s  # sweeps remaining after this one
         sub_tile = tuple(
@@ -374,7 +385,8 @@ def _fused_batched(
     f_padded, ops, phis, plan: StencilPlan, *, aux, interpret
 ):
     """Lower a batched (ensemble) plan: one kernel walks all B members
-    per block instead of B independent launches.
+    per block instead of B independent launches. ``aux`` is the tuple
+    of aux operands, empty for an aux-free plan.
 
     Members are flattened member-major onto the field axis —
     (B, n_f, *sp) → (B·n_f, *sp) — so the staged input window (and its
@@ -396,7 +408,10 @@ def _fused_batched(
         )
     flat = f_padded.reshape((b * plan.n_f,) + f_padded.shape[2:])
     aux_flat = None
-    if aux is not None:
+    if aux:
+        # Member-major flattening needs each member's aux rows adjacent,
+        # so several aux operands are stacked here, in HBM.
+        aux = jnp.concatenate(aux, axis=1) if len(aux) > 1 else aux[0]
         if aux.shape[:2] != (b, plan.n_aux):
             raise ValueError(
                 f"batched aux must be (batch, n_aux, *spatial) = "
@@ -526,8 +541,14 @@ def fused_stencil_pallas(
     the stencil kernel: (n_aux, *interior) at depth 1 (staged as
     halo-free center tiles), (n_aux, *(interior + 2r(S-1))) at temporal
     depth S > 1 (staged as overlapping windows so intermediate sweeps
-    see an aligned carry). ``phi`` may be a sequence of ``fuse_steps``
-    callables (one per fused sweep). Returns (n_out, *interior).
+    see an aligned carry). ``aux`` may also be a tuple of such arrays
+    whose rows sum to ``plan.n_aux``: each is then its own operand with
+    its own BlockSpec, and the blocks are joined row-wise only in VMEM,
+    so arrays that live apart in HBM (a level that changes every step
+    beside coefficient fields that never change) are never stacked
+    there. φ sees the same (n_aux, ...) rows either way. ``phi`` may be
+    a sequence of ``fuse_steps`` callables (one per fused sweep).
+    Returns (n_out, *interior).
 
     When ``plan.batch > 1`` the operands grow a leading ensemble axis —
     ``f_padded`` (batch, n_f, *padded), ``aux`` (batch, n_aux, ...) —
@@ -535,8 +556,16 @@ def fused_stencil_pallas(
     rows, shared halo window; see :func:`_fused_batched`). Returns
     (batch, n_out, *interior).
     """
-    if (aux is not None) != bool(plan.n_aux):
-        raise ValueError("aux operand does not match plan.n_aux")
+    aux_ops = () if aux is None else (
+        tuple(aux) if isinstance(aux, (tuple, list)) else (aux,)
+    )
+    row_axis = 1 if f_padded.ndim == plan.rank + 2 else 0
+    aux_rows = sum(a.shape[row_axis] for a in aux_ops)
+    if aux_rows != plan.n_aux:
+        raise ValueError(
+            f"aux operands carry {aux_rows} rows, plan.n_aux is "
+            f"{plan.n_aux}"
+        )
     phis = (
         tuple(phi)
         if isinstance(phi, (tuple, list))
@@ -547,9 +576,9 @@ def fused_stencil_pallas(
             f"got {len(phis)} phi callables for plan with "
             f"fuse_steps={plan.fuse_steps}"
         )
-    if plan.batch > 1 or f_padded.ndim == plan.rank + 2:
+    if plan.batch > 1 or row_axis:
         return _fused_batched(
-            f_padded, ops, phis, plan, aux=aux, interpret=interpret
+            f_padded, ops, phis, plan, aux=aux_ops, interpret=interpret
         )
     if plan.strategy == "swc_stream":
         return _fused_stream(
@@ -569,40 +598,37 @@ def fused_stencil_pallas(
         )
     ]
     operands = [f_padded]
-    if aux is not None:
-        aux_window = windows["aux_window"]
+    for a in aux_ops:
+        rows = (a.shape[0],) + windows["aux_window"]
         if plan.fuse_steps == 1:
-            in_specs.append(
-                pl.BlockSpec((plan.n_aux,) + aux_window, tile_map)
-            )
+            in_specs.append(pl.BlockSpec(rows, tile_map))
         else:
             in_specs.append(
                 element_window_spec(
-                    (plan.n_aux,) + aux_window,
-                    in_map,
+                    rows, in_map,
                     window_dims=tuple(range(1, plan.rank + 1)),
                 )
             )
-        operands.append(aux)
+        operands.append(a)
     tc = plan.strategy == "tc"
     if plan.fuse_steps > 1:
         name = "stencil_temporal"
         kernel = functools.partial(
             _kernel_temporal, ops=ops, radii=radii, tile=tile,
-            phis=phis, n_f=plan.n_f, has_aux=aux is not None,
+            phis=phis, n_f=plan.n_f, n_aux_refs=len(aux_ops),
             derivs_fn=_block_derivs_tc if tc else _block_derivs,
         )
     elif tc:
         name = "stencil_tc"
         kernel = functools.partial(
             _kernel_tc, ops=ops, radii=radii, tile=tile,
-            phi=phis[0], has_aux=aux is not None,
+            phi=phis[0], n_aux_refs=len(aux_ops),
         )
     else:
         name = "stencil_pipelined"
         kernel = functools.partial(
             _kernel_pipelined, ops=ops, radii=radii, tile=tile,
-            phi=phis[0], unroll=plan.unroll, has_aux=aux is not None,
+            phi=phis[0], unroll=plan.unroll, n_aux_refs=len(aux_ops),
         )
     return pl.pallas_call(
         kernel,

@@ -122,7 +122,7 @@ def fused_stencil_nd(
     phi: Callable[..., jnp.ndarray],
     n_out: int,
     *,
-    aux: jnp.ndarray | None = None,
+    aux: _ref.Aux = None,
     strategy: str = "swc",
     block: tuple[int, ...] | str | None = None,
     unroll: int = 1,
@@ -156,6 +156,10 @@ def fused_stencil_nd(
     block (member-major, shared halo window; 'hwc' uses the ``vmap``
     reference). ``aux`` then carries the same leading axis. Returns
     (batch, n_out, *interior).
+
+    ``aux`` is one array or a tuple of arrays whose rows together are
+    φ's aux rows; the Pallas regimes stage each array as its own operand
+    (``emit.fused_stencil_pallas``), so nothing stacks them in HBM.
     """
     if interpret is None:
         interpret = _default_interpret()
@@ -178,12 +182,16 @@ def fused_stencil_nd(
         from repro.tuning.session import auto_block_nd
 
         block = auto_block_nd(
-            f_padded, ops, phi, n_out, aux=aux, strategy=strategy,
-            unroll=unroll, fuse_steps=fuse_steps, interpret=interpret,
+            f_padded, ops, phi, n_out,
+            aux=_ref.join_aux(aux, 1 if batched else 0),
+            strategy=strategy, unroll=unroll, fuse_steps=fuse_steps,
+            interpret=interpret,
         )
+    aux_shape = None if aux is None else jax.eval_shape(
+        lambda a: _ref.join_aux(a, 1 if batched else 0), aux
+    ).shape
     plan = plan_for_nd(
-        ops, f_padded.shape, n_out,
-        aux_shape=None if aux is None else aux.shape,
+        ops, f_padded.shape, n_out, aux_shape=aux_shape,
         strategy=strategy, block=block, dtype=str(f_padded.dtype),
         unroll=unroll, fuse_steps=fuse_steps,
     )

@@ -8,7 +8,7 @@ paper's L1/L2-managed implementations.
 """
 from __future__ import annotations
 
-from typing import Callable
+from typing import Callable, Union
 
 import jax
 import jax.numpy as jnp
@@ -57,23 +57,36 @@ def apply_operator_set(
     return out
 
 
+Aux = Union[jnp.ndarray, tuple, None]
+
+
+def join_aux(aux: Aux, axis: int = 0) -> jnp.ndarray | None:
+    """Aux rows as the one array φ reads: a tuple of arrays is joined
+    along the row ``axis`` (1 for a batched member stack); an array or
+    None passes through."""
+    if isinstance(aux, (tuple, list)):
+        return jnp.concatenate(aux, axis=axis)
+    return aux
+
+
 def fused_stencil(
     f_padded: jnp.ndarray,
     ops: OperatorSet,
     phi: Callable[..., jnp.ndarray],
-    aux: jnp.ndarray | None = None,
+    aux: Aux = None,
 ) -> jnp.ndarray:
     """The paper's fused φ(A·B) evaluation (Eq. 9), reference form.
 
     Computes all linear operators (Q = A·B at every point) then the
     nonlinear point-wise map φ. ``phi`` maps {op_name: (n_f, *spatial)} to
     (n_out, *spatial). ``aux`` (n_aux, *spatial), if given, provides extra
-    point-wise inputs (e.g. the RK3 carry) passed as phi's second arg.
+    point-wise inputs (e.g. the RK3 carry) passed as phi's second arg; a
+    tuple of arrays is joined row-wise first (:func:`join_aux`).
     """
     derivs = apply_operator_set(f_padded, ops)
     if aux is None:
         return phi(derivs)
-    return phi(derivs, aux)
+    return phi(derivs, join_aux(aux))
 
 
 def fused_stencil_steps(
@@ -81,7 +94,7 @@ def fused_stencil_steps(
     ops: OperatorSet,
     phi,
     n_steps: int,
-    aux: jnp.ndarray | None = None,
+    aux: Aux = None,
 ) -> jnp.ndarray:
     """Sequential reference for temporal fusion: apply the fused op
     ``n_steps`` times, shrinking the valid region by one radius per
@@ -106,7 +119,7 @@ def fused_stencil_steps(
         )
     rad = ops.radius_per_axis()
     n_f = f_padded.shape[0]
-    cur, cur_aux = f_padded, aux
+    cur, cur_aux = f_padded, join_aux(aux)
     for s, phi_s in enumerate(phis):
         out = fused_stencil(cur, ops, phi_s, aux=cur_aux)
         if s == n_steps - 1:
@@ -129,7 +142,7 @@ def fused_stencil_batched(
     f_padded: jnp.ndarray,
     ops: OperatorSet,
     phi: Callable[..., jnp.ndarray],
-    aux: jnp.ndarray | None = None,
+    aux: Aux = None,
 ) -> jnp.ndarray:
     """Batched (ensemble) oracle: ``vmap`` of :func:`fused_stencil`
     over a leading member axis.
@@ -152,7 +165,7 @@ def fused_stencil_steps_batched(
     ops: OperatorSet,
     phi,
     n_steps: int,
-    aux: jnp.ndarray | None = None,
+    aux: Aux = None,
 ) -> jnp.ndarray:
     """Batched sequential reference for temporal fusion: ``vmap`` of
     :func:`fused_stencil_steps` over a leading member axis (see

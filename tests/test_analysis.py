@@ -18,6 +18,8 @@ contract as ``test_kernel_properties.py``).
 """
 import json
 
+import pytest
+
 try:
     import hypothesis.strategies as st
     from hypothesis import given, settings
@@ -185,6 +187,28 @@ def test_vmem_shadow_measurement_matches_model():
         res = audit_plan(plan, OPS2)
         assert res.findings == []
         assert check_vmem(plan, res.measured_vmem) == []
+
+
+@pytest.mark.parametrize(
+    "strategy,fuse,rows",
+    [("swc", 1, (1, 1, 1)), ("tc", 1, (2, 1)), ("swc", 2, (1, 2))],
+)
+def test_split_aux_audits_like_stacked_aux(strategy, fuse, rows):
+    """A tuple ``aux`` (one operand per array, joined in VMEM) proves
+    clean and stages what the stacked operand stages; rows that do not
+    add up to the plan's are a finding."""
+    n_aux = sum(rows)
+    n_out = 1 + n_aux if fuse > 1 else 1
+    pad = 2 * fuse
+    plan = plan_stencil(
+        OPS2, (1, 16 + pad, 256 + pad), n_out, strategy=strategy,
+        n_aux=n_aux, fuse_steps=fuse,
+    )
+    stacked = audit_plan(plan, OPS2)
+    split = audit_plan(plan, OPS2, aux_rows=rows)
+    assert stacked.findings == [] and split.findings == []
+    assert split.measured_vmem == stacked.measured_vmem
+    assert audit_plan(plan, OPS2, aux_rows=rows[1:]).findings
 
 
 def test_vmem_check_flags_mismatch():

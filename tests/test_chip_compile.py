@@ -23,6 +23,7 @@ from chip_smoke import DIFF_N, MHD_BLOCK, MHD_N, SERVE_BATCH, SERVE_SHAPES
 from repro.kernels.emit import fused_stencil_pallas
 from repro.kernels.plan import plan_stencil
 from repro.kernels.stencil1d import xcorr1d_pallas
+from repro.physics.acoustic import AcousticProblem, _phi as acoustic_phi
 from repro.physics.diffusion import DiffusionProblem
 from repro.physics.mhd import MHDSolver, mhd_rhs_phi
 
@@ -70,13 +71,22 @@ def _padded(interior, radii, depth, lead):
 
 def _case(name):
     """(ops, phi, padded operand shape, n_out, plan kwargs) of one
-    kernel family at chip_smoke.py's size."""
+    kernel family at chip_smoke.py's size; plan kwargs with ``n_aux``
+    carry ``aux_rows``, the rows of each aux operand."""
     if name == "mhd_rhs":
         solver = MHDSolver((MHD_N,) * 3, strategy="swc", block=MHD_BLOCK)
         ops = solver.operator_set
         shape = _padded(solver.shape, ops.radius_per_axis(), 1, (8,))
         return ops, mhd_rhs_phi(solver.params), shape, 8, {
             "block": MHD_BLOCK,
+        }
+    if name == "acoustic_o8":
+        # The benchmark's shot: 512³, radius 4 over a zero pad, with
+        # u⁻, a and b as three operands.
+        ops = AcousticProblem((DIFF_N,) * 3).operator_set()
+        shape = _padded((DIFF_N,) * 3, ops.radius_per_axis(), 1, (1,))
+        return ops, acoustic_phi, shape, 1, {
+            "n_aux": 3, "aux_rows": (1, 1, 1),
         }
     if name == "serve_batched_2d":
         ops, phi = _diffusion(SERVE_SHAPE, 2)
@@ -107,13 +117,20 @@ def compiled_text(one_chip):
     def get(name):
         if name not in texts:
             ops, phi, shape, n_out, kw = _case(name)
+            rows = kw.pop("aux_rows", ())
             plan = plan_stencil(ops, shape, n_out, **kw)
             x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
-            texts[name] = jax.jit(
-                lambda f: fused_stencil_pallas(
-                    f, ops, phi, plan, interpret=False
+            aux = [
+                jax.ShapeDtypeStruct(
+                    (r,) + plan.interior, jnp.float32, sharding=one_chip
                 )
-            ).lower(x).compile().as_text()
+                for r in rows
+            ]
+            texts[name] = jax.jit(
+                lambda f, *aux: fused_stencil_pallas(
+                    f, ops, phi, plan, aux=aux or None, interpret=False
+                )
+            ).lower(x, *aux).compile().as_text()
         return texts[name]
 
     return get
@@ -128,6 +145,7 @@ KERNEL_NAMES = {
     "mhd_rhs": "stencil_pipelined",
     "serve_batched_2d": "stencil_pipelined",
     "swc_1d": "stencil_pipelined",
+    "acoustic_o8": "stencil_pipelined",
 }
 
 
