@@ -74,14 +74,19 @@ def _derived_exec_plan(plan: StencilPlan) -> StencilPlan:
 
 def _sweep_exts(plan: StencilPlan) -> list[tuple[int, ...]]:
     """Independently derived per-sweep derivative extents: sweep ``s``
-    of ``S`` sees ``τ + 2r·(S-1-s)`` per axis."""
-    return [
+    of ``S`` sees ``τ + 2r·(S-1-s)`` per axis, except that a z-chunked
+    body (``plan.z_chunk`` below the z tile) sees ``z_chunk`` planes on
+    z in every sweep."""
+    exts = [
         tuple(
             t + 2 * r * (plan.fuse_steps - 1 - s)
             for t, r in zip(plan.block, plan.radii)
         )
         for s in range(plan.fuse_steps)
     ]
+    if plan.z_chunk < plan.block[0]:
+        exts = [(plan.z_chunk,) + e[1:] for e in exts]
+    return exts
 
 
 def _audit_pipelined(
@@ -135,11 +140,19 @@ def _audit_pipelined(
         )
         for i, rows in enumerate(aux_rows)
     ]
+    chunked = plan.z_chunk < plan.block[0]
     phis = make_synthetic_phis(
         plan,
-        _sweep_exts(plan) if plan.fuse_steps > 1 else [plan.block],
+        _sweep_exts(plan) if plan.fuse_steps > 1 or chunked
+        else [plan.block],
         observed_exts=observed,
     )
+    scratch: list[ShadowRef] = []
+    if windows["mid"] is not None:
+        scratch.append(
+            ShadowRef("mid", (plan.n_f,) + windows["mid"], plan.dtype)
+        )
+    z_chunk = plan.z_chunk if chunked else None
     tc = plan.strategy == "tc"
     ctx = ShimContext(program_ids=(0,) * plan.rank)
     try:
@@ -152,16 +165,17 @@ def _audit_pipelined(
             )
             if plan.fuse_steps > 1:
                 emit._kernel_temporal(
-                    f_ref, *rest, o_ref, ops=ops, radii=plan.radii,
-                    tile=plan.block, phis=phis, n_f=plan.n_f,
-                    n_aux_refs=len(rest), derivs_fn=derivs_fn,
+                    f_ref, *rest, o_ref, *scratch, ops=ops,
+                    radii=plan.radii, tile=plan.block, phis=phis,
+                    n_f=plan.n_f, n_aux_refs=len(rest),
+                    derivs_fn=derivs_fn, z_chunk=z_chunk,
                 )
             else:
                 emit._kernel_pipelined(
                     f_ref, *rest, o_ref, ops=ops, radii=plan.radii,
                     tile=plan.block, phi=phis[0],
                     unroll=plan.unroll, n_aux_refs=len(rest),
-                    derivs_fn=derivs_fn,
+                    derivs_fn=derivs_fn, z_chunk=z_chunk,
                 )
     except AuditError as e:
         findings.append(Finding(e.cls, sid, e.detail))
@@ -174,10 +188,14 @@ def _audit_pipelined(
             ))
 
     itemsize = np.dtype(plan.dtype).itemsize
-    mid = (
-        plan.n_f * math.prod(observed[0])
-        if plan.fuse_steps > 1 and observed else 0
-    )
+    # The intermediate generation: the scratch a z-chunked body keeps
+    # it in, else the extent the first sweep's φ returned.
+    if scratch:
+        mid = math.prod(scratch[0].shape)
+    elif plan.fuse_steps > 1 and observed:
+        mid = plan.n_f * math.prod(observed[0])
+    else:
+        mid = 0
     # Mosaic stages halo windows tile-aligned; the body reads only the
     # logical window the shadow refs hold.
     if plan.n_aux and plan.fuse_steps > 1:

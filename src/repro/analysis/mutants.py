@@ -82,7 +82,7 @@ def _temporal_sweeps_skewed(cur, ops, radii, tile, phis, derivs_fn=None):
 
 def _kernel_pipelined_gap(
     f_ref, *rest, ops, radii, tile, phi, unroll, n_aux_refs,
-    derivs_fn=None,
+    derivs_fn=None, z_chunk=None,
 ):
     """_kernel_pipelined that never computes the LAST unroll sub-tile
     — stores stay in bounds but the output tile has a hole."""

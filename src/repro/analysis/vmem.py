@@ -5,11 +5,13 @@ staged — the shadow ref shapes (which are the emitter's own BlockSpec/
 scratch shapes via ``lowering_windows``/``stream_extents``) plus the
 carried intermediate extents OBSERVED at the synthetic-φ boundaries.
 :func:`check_vmem` compares that against
-``repro.tuning.costmodel.vmem_working_set``, which derives the same
-quantity by independent arithmetic (and whose answers steer candidate
-enumeration and the 12 MiB budget filter). Divergence means the tuner
-is budgeting for a different kernel than the one being emitted —
-historically how the unroll and aux terms went missing.
+``repro.kernels.plan.vmem_working_set`` (resolved through its
+re-export in ``repro.tuning.costmodel``), which derives the same
+quantity by independent arithmetic — the one formula whose answers
+steer candidate enumeration, the 12 MiB budget filter and the default
+tile of rank-3 ``swc`` plans. Divergence means the tuner and the
+planner are budgeting for a different kernel than the one being
+emitted — historically how the unroll and aux terms went missing.
 
 Tolerance: the two derivations are exact mirrors, so the default
 relative tolerance is 0 (byte equality). ``tol`` exists for callers

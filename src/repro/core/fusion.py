@@ -113,8 +113,9 @@ class FusedStencilOp:
             block, depth and stream axis jointly and persists them in
             one record; see the module docstring).
         block: rank-length tile (x last), ``"auto"`` (persistent tuning
-            cache), or None (per-rank default; coerced to ``"auto"``
-            under ``strategy="auto"``, which owns the block).
+            cache), or None (the planner's default tile, derived from
+            the shape for rank-3 ``swc``; coerced to ``"auto"`` under
+            ``strategy="auto"``, which owns the block).
         fuse_steps: temporal-fusion depth (int ≥ 1, or ``"auto"`` for
             the joint block/depth search).
 
@@ -147,7 +148,8 @@ class FusedStencilOp:
     # Rank-length tile (x last), "auto" to consult the persistent tuning
     # cache (repro.tuning: cache-hit fast path, rank-and-measure on an
     # eager miss, structural cost-model winner under jit tracing), or
-    # None for the per-rank default.
+    # None for the default tile (plan_stencil: derived from the shape
+    # for rank-3 unbatched swc plans, the fixed per-rank tile otherwise).
     block: tuple[int, ...] | str | None = None
     # Temporal fusion depth: one call advances this many time steps in
     # ONE kernel (halo widened to radius·depth, intermediates VMEM-only).

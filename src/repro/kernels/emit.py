@@ -237,15 +237,53 @@ def _join_rows(blocks):
     return jnp.concatenate(blocks, axis=0)
 
 
+def _z_loop(n_planes: int, chunk: int, body) -> None:
+    """Call ``body(z0)`` over chunks of ``chunk`` planes covering
+    ``n_planes`` in one ``fori_loop``. When ``chunk`` does not divide
+    ``n_planes`` the last chunk starts at ``n_planes - chunk`` and
+    recomputes planes an earlier chunk wrote, with the same values."""
+    last = n_planes - chunk
+
+    def step(i, carry):
+        body(jnp.minimum(i * chunk, last))
+        return carry
+
+    jax.lax.fori_loop(0, -(-n_planes // chunk), step, 0)
+
+
 def _kernel_pipelined(
     f_ref, *rest, ops, radii, tile, phi, unroll, n_aux_refs,
-    derivs_fn=_block_derivs,
+    derivs_fn=_block_derivs, z_chunk=None,
 ):
     """Pipelined kernel, any rank. ``rest`` is (*aux_refs, o_ref): one
     ref per aux operand (``n_aux_refs`` of them, 0 for an aux-free
     plan), joined row-wise for φ. ``derivs_fn`` selects the
-    tap-evaluation lowering (VPU shifted slices or MXU contractions)."""
+    tap-evaluation lowering (VPU shifted slices or MXU contractions).
+
+    A ``z_chunk`` below the rank-3 z tile walks the staged block in
+    chunks of that many output planes (``plan.z_chunk``), each reading
+    its ``z_chunk + 2r`` window planes: the same taps in the same order
+    per point, with a body Mosaic unrolls once per chunk, not once per
+    tile."""
     aux_refs, o_ref = rest[:n_aux_refs], rest[n_aux_refs]
+    if z_chunk is not None and z_chunk < tile[0]:
+        sub = (z_chunk,) + tuple(tile[1:])
+
+        def chunk(z0):
+            derivs = derivs_fn(
+                f_ref[:, pl.ds(z0, z_chunk + 2 * radii[0])],
+                ops, radii, sub,
+            )
+            if aux_refs:
+                val = phi(derivs, _join_rows(
+                    [r[:, pl.ds(z0, z_chunk)] for r in aux_refs]
+                ))
+            else:
+                val = phi(derivs)
+            o_ref[:, pl.ds(z0, z_chunk)] = val
+
+        _z_loop(tile[0], z_chunk, chunk)
+        return
     fblk = f_ref[...]
     tx = tile[-1]
     rx = radii[-1]
@@ -304,7 +342,7 @@ def _temporal_sweeps(
 
 def _kernel_temporal(
     f_ref, *rest, ops, radii, tile, phis, n_f, n_aux_refs,
-    derivs_fn=_block_derivs,
+    derivs_fn=_block_derivs, z_chunk=None,
 ):
     """Temporal-fusion kernel, any rank: apply the fused op
     ``len(phis)`` times on one VMEM-resident block staged with a
@@ -318,8 +356,32 @@ def _kernel_temporal(
     intermediate sweep sees a point-wise-aligned carry. The aux-free
     case delegates to :func:`_temporal_sweeps` (shared with the
     streaming kernel) so the sweep-shrinking arithmetic lives once.
+
+    A ``z_chunk`` below the z tile (``plan.z_chunk``: depth 2, aux-free,
+    rank 3) runs each sweep as a loop over chunks of that many planes:
+    sweep 1 writes the ``tile + 2r`` intermediate generation to the VMEM
+    scratch ref that then follows ``o_ref`` in ``rest``, and sweep 2
+    reads it back — the same taps in the same order per point.
     """
     aux_refs, o_ref = rest[:n_aux_refs], rest[n_aux_refs]
+    if z_chunk is not None and z_chunk < tile[0]:
+        (mid_ref,) = rest[n_aux_refs + 1:]
+        mid = tuple(t + 2 * r for t, r in zip(tile, radii))
+
+        def sweep(src, dst, phi, ext, n_planes):
+            def chunk(z0):
+                val = phi(derivs_fn(
+                    src[:, pl.ds(z0, z_chunk + 2 * radii[0])],
+                    ops, radii, (z_chunk,) + tuple(ext),
+                ))
+                dst[:, pl.ds(z0, z_chunk)] = val[: dst.shape[0]]
+
+            _z_loop(n_planes, z_chunk, chunk)
+
+        first, last = phis
+        sweep(f_ref, mid_ref, first, mid[1:], mid[0])
+        sweep(mid_ref, o_ref, last, tile[1:], tile[0])
+        return
     if not aux_refs:
         o_ref[...] = _temporal_sweeps(
             f_ref[...], ops, radii, tile, phis, derivs_fn=derivs_fn
@@ -442,7 +504,10 @@ def lowering_windows(plan: StencilPlan) -> dict[str, tuple[int, ...]]:
     input block (halo-widened, x spanning all ``unroll`` sub-tiles);
     ``out_tile`` — the output block; ``aux_window`` — the staged aux
     block (``None`` for aux-free plans): halo-free at depth 1, widened
-    by ``r·(S-1)`` per axis at temporal depth ``S > 1``.
+    by ``r·(S-1)`` per axis at temporal depth ``S > 1``; ``mid`` — the
+    VMEM scratch a z-chunked temporal kernel keeps its intermediate
+    generation in (``tile + 2r``; ``None`` unless ``plan.z_chunk`` is
+    below the z tile at depth > 1).
     """
     radii, tile = plan.radii, plan.block
     window = tuple(
@@ -459,8 +524,14 @@ def lowering_windows(plan: StencilPlan) -> dict[str, tuple[int, ...]]:
                 t + 2 * r * (plan.fuse_steps - 1)
                 for t, r in zip(tile, radii)
             )
+    mid = None
+    if plan.fuse_steps > 1 and plan.z_chunk < tile[0]:
+        mid = tuple(
+            t + 2 * r * (plan.fuse_steps - 1) for t, r in zip(tile, radii)
+        )
     return {
         "window": window, "out_tile": out_tile, "aux_window": aux_window,
+        "mid": mid,
     }
 
 
@@ -611,13 +682,21 @@ def fused_stencil_pallas(
             )
         operands.append(a)
     tc = plan.strategy == "tc"
+    z_chunk = plan.z_chunk
+    chunked = z_chunk < tile[0]
+    scratch = []
     if plan.fuse_steps > 1:
         name = "stencil_temporal"
         kernel = functools.partial(
             _kernel_temporal, ops=ops, radii=radii, tile=tile,
             phis=phis, n_f=plan.n_f, n_aux_refs=len(aux_ops),
             derivs_fn=_block_derivs_tc if tc else _block_derivs,
+            z_chunk=z_chunk if chunked else None,
         )
+        if chunked:
+            scratch = [pltpu.VMEM(
+                (plan.n_f,) + windows["mid"], f_padded.dtype
+            )]
     elif tc:
         name = "stencil_tc"
         kernel = functools.partial(
@@ -629,6 +708,7 @@ def fused_stencil_pallas(
         kernel = functools.partial(
             _kernel_pipelined, ops=ops, radii=radii, tile=tile,
             phi=phis[0], unroll=plan.unroll, n_aux_refs=len(aux_ops),
+            z_chunk=z_chunk if chunked else None,
         )
     return pl.pallas_call(
         kernel,
@@ -638,6 +718,7 @@ def fused_stencil_pallas(
         out_shape=jax.ShapeDtypeStruct(
             (plan.n_out,) + plan.interior, f_padded.dtype
         ),
+        scratch_shapes=scratch,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name=name,

@@ -136,7 +136,8 @@ def fused_stencil_nd(
     any rank) or 'swc_stream' (Pallas explicit streaming of the slowest
     axis with carried halo planes + prefetch DMA, paper Fig. 5b —
     z-streaming at rank 3, y-streaming at rank 2). ``block`` is a
-    rank-length tile (``None`` → per-rank default; longer tuples keep
+    rank-length tile (``None`` → the planner's default, see
+    :func:`~repro.kernels.plan.plan_stencil`; longer tuples keep
     their trailing, x-last entries; non-divisible extents shrink the
     tile to the largest divisor) or ``"auto"``, which consults the
     persistent tuning cache (measuring on a miss when eager) — for

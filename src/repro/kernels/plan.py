@@ -21,7 +21,10 @@ blocks follow the same order, e.g. (τz, τy, τx) at rank 3.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import TYPE_CHECKING, Sequence
+
+import numpy as np
 
 from repro.core.stencil import OperatorSet, StencilSpec
 
@@ -48,7 +51,10 @@ AXIS_LETTERS: dict[int, tuple[str, ...]] = {
 
 # Per-rank default tiles: x spans the lane dimension (long 1-D blocks
 # amortize per-grid-step pipeline overhead), y/z follow the paper's
-# TPU-friendly bases.
+# TPU-friendly bases. Rank-3 ``swc`` plans derive their default from
+# the shape instead (:func:`default_block`); the (8, 8, 128) entry
+# serves the rank-3 plans that rule leaves alone (batched, unrolled,
+# ``swc_stream``, ``tc``).
 DEFAULT_BLOCKS: dict[int, tuple[int, ...]] = {
     1: (2048,),
     2: (16, 128),
@@ -58,11 +64,16 @@ DEFAULT_BLOCKS: dict[int, tuple[int, ...]] = {
 
 # Scoped VMEM each fused-stencil kernel may use, handed to Mosaic as
 # ``vmem_limit_bytes`` (its default is 16 MiB). A TPU v5e core has 128
-# MiB of VMEM. The tuner budgets only the staged blocks against an
-# eighth of it (``repro.tuning.costmodel.VMEM_BUDGET``); the rest holds
-# φ's temporaries, which dominate for the MHD φ: its depth-2 RK-pair
-# kernel at block (2, 8, 128) needs ~81 MiB.
+# MiB of VMEM. The tuner and the default-tile rule budget only the
+# staged blocks against an eighth of it (:data:`VMEM_BUDGET`); the rest
+# holds φ's temporaries, which dominate for the MHD φ: its depth-2
+# RK-pair kernel at block (2, 8, 128) needs ~81 MiB.
 VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+
+# VMEM the staged blocks of one tile may take (bytes), by
+# :func:`vmem_working_set`: an eighth of the scoped limit, leaving the
+# rest for φ's temporaries, which that formula does not count.
+VMEM_BUDGET = VMEM_LIMIT_BYTES // 8
 
 # Mosaic's (sublane, lane) tiling of the last two dims of a 32-bit
 # VMEM block. A staged halo window (τ + 2r per axis) is rounded up to
@@ -111,6 +122,268 @@ def largest_divisor_leq(n: int, cap: int) -> int:
         if n % t == 0:
             return t
     return 1
+
+
+def _staged_elements(
+    block: Sequence[int],
+    radii: Sequence[int],
+    n_f: int,
+    n_out: int,
+    fuse_steps: int = 1,
+    unroll: int = 1,
+    n_aux: int = 0,
+) -> tuple[int, int, int, int]:
+    """Elements one grid step of the pipelined lowering holds in VMEM:
+    ``(inp, aux, mid, out)`` — the input halo window and the aux blocks
+    at the extents Mosaic stages them (:func:`staged_window`), one
+    intermediate field generation at temporal depth > 1, and the output
+    tile. A depth-1 aux operand is a halo-free output-shaped tile; at
+    depth S it is the ``r·(S-1)``-widened window. The shapes mirror
+    ``emit.lowering_windows``; :func:`vmem_working_set` and
+    :attr:`StencilPlan.staged_per_output` both count from here."""
+    last = len(block) - 1
+    steps = tuple(t * unroll if a == last else t for a, t in enumerate(block))
+    halo_win = tuple(s + 2 * r * fuse_steps for s, r in zip(steps, radii))
+    carry_win = tuple(
+        t + 2 * r * (fuse_steps - 1) for t, r in zip(block, radii)
+    )
+    inp = n_f * math.prod(staged_window(halo_win))
+    aux = n_aux * (
+        math.prod(steps) if fuse_steps == 1
+        else math.prod(staged_window(carry_win))
+    )
+    mid = (n_f if fuse_steps > 1 else 0) * math.prod(carry_win)
+    return inp, aux, mid, n_out * math.prod(steps)
+
+
+def vmem_working_set(
+    block: Sequence[int],
+    radii: Sequence[int],
+    n_f: int,
+    n_out: int,
+    itemsize: int,
+    fuse_steps: int = 1,
+    stream: bool = False,
+    *,
+    batch: int = 1,
+    unroll: int = 1,
+    n_aux: int = 0,
+) -> int:
+    """VMEM footprint of one block, any rank — the ONE working-set
+    formula shared by the default-tile rule (:func:`default_block`), the
+    tuner's candidate filter (``repro.tuning.costmodel``) and the
+    auditor's fidelity check (``repro.analysis.vmem``). Temporal fusion
+    widens the staged window to ``radii * fuse_steps`` and holds one
+    intermediate field generation on-chip between sweeps.
+
+    ``stream=True`` models the explicit-streaming kernel's scratch
+    instead: the working buffer (tile + widened halo on every axis),
+    two prefetch buffers (τ₀ fresh planes × the cross window), and the
+    output staging tile — the shapes ``emit._fused_stream`` allocates.
+
+    ``batch`` is the ensemble extent of a batched launch: the member-
+    major lowering stages all B members' field rows in one window, so
+    every field-count term scales by B — which is why the batched
+    candidate enumeration picks smaller blocks at larger B.
+
+    Halo windows count at the tile-aligned extents Mosaic stages
+    (:func:`staged_window`): the lane axis rounded up to 128 and the
+    sublane axis to 8.
+
+    ``unroll`` is the element-wise unroll factor of a pipelined plan:
+    the staged window and output tile span all ``unroll`` x sub-tiles
+    per grid step (``τx·unroll + 2r`` / ``τx·unroll``), so an unrolled
+    block is NOT the footprint of its base block — before this term
+    the model under-counted unrolled plans by nearly ``unroll``×.
+    ``n_aux`` counts point-wise aux operands, staged (and, like every
+    pipelined input, double-buffered) as a halo-free tile at depth 1
+    and an ``r·(S-1)``-widened window at temporal depth S. Streaming
+    plans reject both (plan validation), so the kwargs are ignored for
+    ``stream=True``. The shapes here mirror
+    ``emit.lowering_windows``/``emit.stream_extents`` — the fidelity
+    contract ``repro.analysis.vmem`` checks per lowerable plan.
+    """
+    if batch < 1:
+        raise ValueError(f"batch must be >= 1, got {batch}")
+    block, radii = tuple(block), tuple(radii)
+    n_f = n_f * batch
+    n_out = n_out * batch
+    n_aux = n_aux * batch
+    if stream:
+        _, _, mid, out = _staged_elements(
+            block, radii, n_f, n_out, fuse_steps
+        )
+        halo_win = tuple(
+            t + 2 * r * fuse_steps for t, r in zip(block, radii)
+        )
+        cross = staged_window(halo_win[1:])
+        work = n_f * halo_win[0] * math.prod(cross)
+        pf = n_f * block[0] * math.prod(cross)
+        return (work + 2 * pf + mid + out) * itemsize
+    inp, aux, mid, out = _staged_elements(
+        block, radii, n_f, n_out, fuse_steps, unroll, n_aux
+    )
+    # Pallas double-buffers pipelined input blocks: 2x input (and aux).
+    return (2 * inp + 2 * aux + mid + out) * itemsize
+
+
+# Largest loop body, in (8, 128) vreg tiles, that one iteration of a
+# rank-3 ``swc`` kernel computes: z-chunk planes times the vregs of one
+# (y, x) plane of the widest region a sweep computes, per field. Mosaic
+# unrolls the body per vreg, so its compile time grows with this
+# figure; the fixed (8, 8, 128) default unrolled 8 × 4 at depth 2.
+BODY_VREGS = 64
+
+
+def plane_vregs(
+    block: Sequence[int], radii: Sequence[int], fuse_steps: int = 1
+) -> int:
+    """Vreg tiles of one (y, x) plane of the widest region a rank-3
+    sweep computes: the tile widened by ``r·(S-1)``, rounded up to the
+    (sublane, lane) tiling."""
+    y, x = (
+        t + 2 * r * (fuse_steps - 1)
+        for t, r in zip(tuple(block)[1:], tuple(radii)[1:])
+    )
+    return (tile_aligned(y, SUBLANE) // SUBLANE) * (
+        tile_aligned(x, LANE) // LANE
+    )
+
+
+def body_z_chunk(
+    block: Sequence[int],
+    radii: Sequence[int],
+    fuse_steps: int = 1,
+    n_aux: int = 0,
+) -> int:
+    """Output z planes one iteration of a rank-3 ``swc`` kernel body
+    computes (``unroll=1``): the largest divisor of the z tile whose
+    body stays within :data:`BODY_VREGS`. The emitter walks the staged
+    block in chunks of this many planes with a ``fori_loop`` (one
+    iteration, i.e. the whole tile unrolled, when the tile is within
+    the bound). Temporal fusion loops at depth 2 without aux carries
+    only (its one intermediate generation goes to a VMEM scratch);
+    other depths and aux-carrying temporal plans keep the whole tile.
+    """
+    tz = int(block[0])
+    if fuse_steps > 2 or (fuse_steps > 1 and n_aux):
+        return tz
+    per_plane = plane_vregs(block, radii, fuse_steps)
+    return largest_divisor_leq(tz, max(1, BODY_VREGS // per_plane))
+
+
+def _divisors(n: int, multiple: int = 1) -> list[int]:
+    """Divisors of ``n`` that are multiples of ``multiple``, plus ``n``."""
+    return sorted(
+        {d for d in range(multiple, n + 1, multiple) if n % d == 0} | {n}
+    )
+
+
+# Staged bytes HBM streams in the time the kernel body takes for one
+# vreg-tap — one tap's multiply-add over one (8, 128) tile of one field.
+# Measured on a TPU v5e (819 GB/s): the 512³ acoustic launch at
+# (16, 32, 512) runs 5.73 M vreg-taps in 5.11 ms, 1.12 a nanosecond;
+# the depth-2 diffusion launches run 0.94–1.02.
+VREG_TAP_BYTES = 730
+
+
+def vreg_sweeps_per_output(
+    block: Sequence[int], radii: Sequence[int], fuse_steps: int = 1
+) -> float:
+    """(8, 128) vreg tiles the sweeps of one rank-3 tile read per
+    output point, per tap and field: sweep ``s`` computes the region
+    ``τ + 2r·(S-1-s)`` plane by plane, each tap reading a (y, x) plane
+    of the region widened by ``r``, rounded up to the (sublane, lane)
+    tiling. The lane and sublane overhang of a tile is what this counts
+    beyond its points."""
+    ry, rx = tuple(radii)[1:]
+    total = 0
+    for margin in range(fuse_steps):
+        z, y, x = (t + 2 * r * margin for t, r in zip(block, radii))
+        total += z * (tile_aligned(y + 2 * ry, SUBLANE) // SUBLANE) * (
+            tile_aligned(x + 2 * rx, LANE) // LANE
+        )
+    return total / math.prod(block)
+
+
+def staged_bytes_per_output(
+    block: Sequence[int],
+    radii: Sequence[int],
+    n_f: int,
+    n_out: int,
+    itemsize: int,
+    fuse_steps: int = 1,
+    n_aux: int = 0,
+    unroll: int = 1,
+) -> float:
+    """Bytes a pipelined launch stages per output point: the input
+    window and aux blocks at their staged extents plus the output tile,
+    over the points of one grid step (:func:`_staged_elements`)."""
+    inp, aux, _, out = _staged_elements(
+        block, radii, n_f, n_out, fuse_steps, unroll, n_aux
+    )
+    return (inp + aux + out) * itemsize / (math.prod(block) * unroll)
+
+
+def default_block(
+    interior: Sequence[int],
+    radii: Sequence[int],
+    n_f: int,
+    n_out: int,
+    itemsize: int,
+    fuse_steps: int = 1,
+    n_aux: int = 0,
+    taps: int = 1,
+) -> tuple[int, int, int] | None:
+    """The default tile of a rank-3, unbatched ``swc`` plan, derived
+    from its shape and its ``taps`` per field and sweep.
+
+    Candidates: z any divisor of the interior; y a multiple of 8
+    dividing it, or the full extent; x a multiple of 128 dividing it,
+    or the full extent — so :func:`tpu_tile_ok` holds. A candidate must
+    fit :data:`VMEM_BUDGET` by :func:`vmem_working_set`, and its loop
+    body (:func:`body_z_chunk` planes of :func:`plane_vregs`) must stay
+    within :data:`BODY_VREGS`.
+
+    The pick has the least time per output point by a two-resource
+    count in staged bytes: the larger of the bytes the launch stages
+    (:func:`staged_bytes_per_output`) and the vreg-taps its body
+    computes (``n_f · taps ·`` :func:`vreg_sweeps_per_output`) at
+    :data:`VREG_TAP_BYTES` each. Ties go to fewer staged bytes, then to
+    fewer grid steps, then to the smaller working set. ``None`` when no
+    candidate fits."""
+    nz, ny, nx = (int(n) for n in interior)
+    best = None
+    for blk in (
+        (z, y, x)
+        for z in _divisors(nz)
+        for y in _divisors(ny, SUBLANE)
+        for x in _divisors(nx, LANE)
+    ):
+        chunk = body_z_chunk(blk, radii, fuse_steps, n_aux)
+        if chunk * plane_vregs(blk, radii, fuse_steps) > BODY_VREGS:
+            continue
+        vmem = vmem_working_set(
+            blk, radii, n_f, n_out, itemsize, fuse_steps, n_aux=n_aux
+        )
+        if vmem > VMEM_BUDGET:
+            continue
+        staged = staged_bytes_per_output(
+            blk, radii, n_f, n_out, itemsize, fuse_steps, n_aux
+        )
+        compute = (
+            n_f * taps * VREG_TAP_BYTES
+            * vreg_sweeps_per_output(blk, radii, fuse_steps)
+        )
+        key = (
+            max(staged, compute),
+            staged,
+            (nz // blk[0]) * (ny // blk[1]) * (nx // blk[2]),
+            vmem,
+        )
+        if best is None or key < best[0]:
+            best = (key, blk)
+    return None if best is None else best[1]
 
 
 def tc_axis_groups(
@@ -434,6 +707,34 @@ class StencilPlan:
         steps = self.block[:-1] + (self.x_step,)
         return tuple(n // t for n, t in zip(self.interior, steps))
 
+    @property
+    def z_chunk(self) -> int:
+        """Output z planes one iteration of the kernel body computes
+        (:func:`body_z_chunk`) for a rank-3 ``swc`` plan without
+        element-wise unrolling; every other plan computes its whole
+        tile at once, so this is ``block[0]`` there."""
+        if (self.rank, self.strategy, self.unroll) != (3, "swc", 1):
+            return self.block[0]
+        return body_z_chunk(
+            self.block, self.radii, self.fuse_steps, self.n_aux
+        )
+
+    @property
+    def staged_per_output(self) -> float | None:
+        """Bytes one launch stages in VMEM per output point: the input
+        halo window and the aux blocks at their tile-aligned staged
+        extents, plus the output tile, over the tile's points — the
+        staged side of the count :func:`default_block` minimises.
+        ``None`` for ``swc_stream`` plans, which stage through their
+        own scratch."""
+        if self.strategy == "swc_stream":
+            return None
+        return staged_bytes_per_output(
+            self.block, self.radii, self.n_f, self.n_out,
+            np.dtype(self.dtype).itemsize, self.fuse_steps, self.n_aux,
+            self.unroll,
+        )
+
     # -- serialization (the tuning layer keys on this) ----------------------
 
     @property
@@ -496,8 +797,14 @@ def plan_stencil(
     operand — a leading extent beyond rank+1 axes is read as the batch.
     An explicit ``batch`` kwarg must agree with a batched shape (and
     turns a rank+1 shape into a plan for a B-member launch).
-    ``block`` may be ``None`` (per-rank default), an int (rank-1
-    shorthand), or a tuple; a tuple longer than the rank keeps its
+    ``block`` may be ``None`` (the default tile), an int (rank-1
+    shorthand), or a tuple. The default of a rank-3, unbatched ``swc``
+    plan without element-wise unrolling is derived from its shape by
+    :func:`default_block` (the tile with the least staged bytes or
+    vreg-taps per output point that fits :data:`VMEM_BUDGET`; its
+    kernel body then walks the tile in :attr:`StencilPlan.z_chunk`
+    planes); every other plan takes :data:`DEFAULT_BLOCKS` of its rank,
+    clamped as an explicit tile is. A tuple longer than the rank keeps its
     trailing entries (x-last convention, so a 3-D default like
     (8, 8, 128) lowers to (8, 128) at rank 2), and each axis is clamped
     to the largest divisor of the interior extent — non-block-divisible
@@ -541,6 +848,12 @@ def plan_stencil(
             f"radii {radii} at fuse_steps={fuse_steps}"
         )
 
+    if block is None and (rank, strategy, batch, unroll) == (3, "swc", 1, 1):
+        block = default_block(
+            interior, radii, int(padded_shape[0]), int(n_out),
+            np.dtype(dtype).itemsize, fuse_steps, n_aux,
+            taps=sum(len(spec.offsets) for spec in ops.ops),
+        )
     if block is None:
         block = DEFAULT_BLOCKS[rank]
     if isinstance(block, int):

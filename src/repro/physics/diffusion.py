@@ -73,7 +73,7 @@ class DiffusionProblem:
         jointly with block/depth/stream —
         ``block`` defaults to ``"auto"`` in that case). ``block`` is a
         rank-length tile, ``"auto"`` for the persistent tuning cache,
-        or None for the per-rank default. ``fuse_steps`` is the
+        or None for the planner's default tile. ``fuse_steps`` is the
         temporal-fusion depth (each op call then advances that many
         Euler steps in one kernel, streamed or pipelined); ``"auto"``
         resolves block and depth jointly from the traffic model.

@@ -42,17 +42,12 @@ from repro.kernels.plan import (
     LANE,
     SUBLANE,
     TC_MAX_TILE,
-    VMEM_LIMIT_BYTES,
-    staged_window,
+    VMEM_BUDGET,
     tpu_tile_ok,
+    vmem_working_set,
 )
 
 log = logging.getLogger("repro.tuning")
-
-# VMEM the staged blocks of one candidate may take (bytes): an eighth
-# of the scoped limit the emitter hands Mosaic, leaving the rest for
-# φ's temporaries, which this model does not count.
-VMEM_BUDGET = VMEM_LIMIT_BYTES // 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,85 +106,6 @@ def temporal_compute_weight(
         return TEMPORAL_COMPUTE_WEIGHT
     hbm_time = (n_f + n_out) * itemsize / peak_hbm_bw(backend)
     return (flops_per_point / peak_vpu_flops(backend)) / hbm_time
-
-
-def vmem_working_set(
-    block: Sequence[int],
-    radii: Sequence[int],
-    n_f: int,
-    n_out: int,
-    itemsize: int,
-    fuse_steps: int = 1,
-    stream: bool = False,
-    *,
-    batch: int = 1,
-    unroll: int = 1,
-    n_aux: int = 0,
-) -> int:
-    """VMEM footprint of one block, any rank. Temporal fusion widens
-    the staged window to ``radii * fuse_steps`` and holds one
-    intermediate field generation on-chip between sweeps.
-
-    ``stream=True`` models the explicit-streaming kernel's scratch
-    instead: the working buffer (tile + widened halo on every axis),
-    two prefetch buffers (τ₀ fresh planes × the cross window), and the
-    output staging tile — the shapes ``emit._fused_stream`` allocates.
-
-    ``batch`` is the ensemble extent of a batched launch: the member-
-    major lowering stages all B members' field rows in one window, so
-    every field-count term scales by B — which is why the batched
-    candidate enumeration picks smaller blocks at larger B.
-
-    Halo windows count at the tile-aligned extents Mosaic stages
-    (:func:`~repro.kernels.plan.staged_window`): the lane axis rounded
-    up to 128 and the sublane axis to 8.
-
-    ``unroll`` is the element-wise unroll factor of a pipelined plan:
-    the staged window and output tile span all ``unroll`` x sub-tiles
-    per grid step (``τx·unroll + 2r`` / ``τx·unroll``), so an unrolled
-    block is NOT the footprint of its base block — before this term
-    the model under-counted unrolled plans by nearly ``unroll``×.
-    ``n_aux`` counts point-wise aux operands, staged (and, like every
-    pipelined input, double-buffered) as a halo-free tile at depth 1
-    and an ``r·(S-1)``-widened window at temporal depth S. Streaming
-    plans reject both (plan validation), so the kwargs are ignored for
-    ``stream=True``. The shapes here mirror
-    ``emit.lowering_windows``/``emit.stream_extents`` — the fidelity
-    contract ``repro.analysis.vmem`` checks per lowerable plan.
-    """
-    if batch < 1:
-        raise ValueError(f"batch must be >= 1, got {batch}")
-    block, radii = tuple(block), tuple(radii)
-    n_f = n_f * batch
-    n_out = n_out * batch
-    n_aux = n_aux * batch
-    last = len(block) - 1
-    steps = tuple(
-        t * unroll if a == last and not stream else t
-        for a, t in enumerate(block)
-    )
-    halo_win = tuple(
-        s + 2 * r * fuse_steps for s, r in zip(steps, radii)
-    )
-    carry_win = tuple(
-        t + 2 * r * (fuse_steps - 1) for t, r in zip(block, radii)
-    )
-    mid = (n_f if fuse_steps > 1 else 0) * math.prod(carry_win)
-    out = n_out * math.prod(steps)
-    if stream:
-        cross = staged_window(halo_win[1:])
-        work = n_f * halo_win[0] * math.prod(cross)
-        pf = n_f * block[0] * math.prod(cross)
-        return (work + 2 * pf + mid + out) * itemsize
-    # Halo windows are staged tile-aligned (plan.staged_window); a
-    # depth-1 aux operand is a halo-free output-shaped tile.
-    inp = n_f * math.prod(staged_window(halo_win))
-    aux = n_aux * (
-        math.prod(steps) if fuse_steps == 1
-        else math.prod(staged_window(carry_win))
-    )
-    # Pallas double-buffers pipelined input blocks: 2x input (and aux).
-    return (2 * inp + 2 * aux + mid + out) * itemsize
 
 
 def halo_overhead(
