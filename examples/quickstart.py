@@ -1,22 +1,16 @@
-"""Quickstart: the three layers of the framework in ~a minute on CPU.
+"""Quickstart: the stencil engine in ~a minute on CPU.
 
 1. The paper's fused stencil engine (φ(A·B)) on a 3-D multiphysics RHS,
    HWC vs SWC strategies agreeing bitwise-ish.
 2. The diffusion equation solved with ONE merged cross-correlation kernel
    (paper Eq. 5-7), validated against the exact discrete eigenvalue.
-3. A reduced LM architecture from the zoo taking real train steps.
 
-Demos 1-2 are the paper reproduction: everything they touch lives in
-``repro.{core,kernels,physics,tuning}`` (the StencilPlan pipeline —
-see docs/architecture.md). Demo 3 is NOT part of the stencil pipeline:
-``repro.models`` / ``repro.configs`` are the beyond-paper architecture
-zoo that reuses the same kernel techniques (e.g. mamba2's depthwise
-conv); skip it if you are here for the stencils.
+Everything they touch lives in ``repro.{core,kernels,physics,tuning}``
+(the StencilPlan pipeline — see docs/architecture.md).
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -65,35 +59,10 @@ def diffusion_demo():
           f"exact eigenvalue^50 {lam**50:.6f}\n")
 
 
-def lm_demo():
-    print("=== 3. architecture zoo (beyond-paper; not the stencil "
-          "pipeline): one real train step ===")
-    from repro.configs.registry import get_config, get_model, reduced_config
-    from repro.optim import AdamWConfig, adamw_init, adamw_update
-
-    for arch in ("qwen2.5-3b", "mamba2-780m", "mixtral-8x7b"):
-        cfg = reduced_config(get_config(arch))
-        api = get_model(cfg)
-        key = jax.random.PRNGKey(0)
-        params = api.init_params(cfg, key)
-        tokens = jax.random.randint(key, (2, 32), 0, cfg.vocab)
-        batch = {"tokens": tokens, "labels": jnp.roll(tokens, -1, 1)}
-        (loss, _), grads = jax.value_and_grad(api.lm_loss, has_aux=True)(
-            params, cfg, batch
-        )
-        params, _, m = adamw_update(
-            AdamWConfig(), grads, adamw_init(params), params
-        )
-        print(f"  {arch:16s} [{cfg.family}] loss={float(loss):.3f} "
-              f"gnorm={float(m['grad_norm']):.2f}")
-    print()
-
-
 if __name__ == "__main__":
     from repro.compile_cache import use_compile_cache
 
     use_compile_cache()
     stencil_demo()
     diffusion_demo()
-    lm_demo()
     print("quickstart OK")

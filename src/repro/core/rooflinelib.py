@@ -262,16 +262,6 @@ def from_compiled(
     )
 
 
-def model_flops_train(n_params: float, n_tokens: float) -> float:
-    """6·N·D (fwd 2ND + bwd 4ND) — dense; pass active params for MoE."""
-    return 6.0 * n_params * n_tokens
-
-
-def model_flops_decode(n_params: float, n_tokens: float) -> float:
-    """2·N per generated token (fwd only)."""
-    return 2.0 * n_params * n_tokens
-
-
 def stencil_ideal_bytes(
     n_points: float, n_f: int, n_out: int, dtype_bytes: int
 ) -> float:

@@ -1,154 +1,20 @@
-"""Modeled per-chip HBM traffic — the *fused* memory roofline term.
+"""Modeled per-chip HBM traffic of stencil launches — the *fused*
+memory roofline term.
 
-``compiled.cost_analysis()['bytes accessed']`` sums every HLO op's
-operand+result bytes as if nothing were fused — on the CPU backend this
-overstates real HBM traffic by 10-30× (every intermediate counted).
-We report that number as the spec'd upper bound, AND this analytic model
-of what a fused TPU execution actually moves:
-
-train step (per chip):
-  params     f32 master read + bf16 cast write/read + f32 write (update)
-  adam       mu, nu: read + write (f32)
-  grads      write + read (f32)
-  acts       remat-saved activations: write (fwd) + read (bwd)
-  logits     write + read of the sharded logits block (f32-equivalent)
-  batch      token ids + label reads (negligible, included)
-
-decode step (per chip):
-  params     one bf16-equivalent read of ACTIVE params
-  kv/state   full cache read + one-slot write
-
-prefill:
-  params     one bf16 read
-  acts       write+read once per layer boundary (no backward)
-
-Assumptions (documented in EXPERIMENTS.md §Roofline): parameters are
-TP-sharded over `model` (÷M), FSDP additionally over `data`; activations
-are DP-sharded (÷D on tokens) with hidden dims TP-sharded where the rules
-shard them. Within ~2× of a real profile, which is what a roofline term
-needs.
-
-The second half of this module models *stencil* HBM traffic under
-temporal fusion (fuse_steps in-kernel time steps on halo-widened
-blocks): what one simulated time step moves through HBM as a function
+What one simulated time step moves through HBM under temporal fusion
+(fuse_steps in-kernel time steps on halo-widened blocks), as a function
 of (block, radii, depth), with a separate function for the explicit-
 streaming kernel (whose carried halo planes eliminate the stream-axis
 halo re-fetch). ``repro.tuning.costmodel`` scores its joint
 (block, fuse_steps, stream) candidates through these exact functions,
 so the autotuner's temporal/streaming terms and the reported traffic
-model cannot diverge.
+model cannot diverge. The per-device peak rates (``DEVICE_PEAKS``) the
+roofline divides by live here too.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Sequence
-
-from repro.models.config import ModelConfig
-
-
-def _act_bytes_per_token_layer(cfg: ModelConfig, model_ways: int) -> float:
-    """Remat-saved bytes per token per layer (bf16), TP-sharded dims ÷M."""
-    d = cfg.d_model
-    m = model_ways
-    if cfg.family == "ssm":
-        dv = cfg.d_inner
-        # in_proj out (2dv+2gn+h)/M, conv out, ssd y, out_proj in
-        per = (2 * dv + 2 * cfg.ssm_n_groups * cfg.ssm_state) / m * 3 + d
-    elif cfg.hybrid_pattern:
-        w = cfg.lru_width or d
-        per = (3 * w / m + d) * 2 / 3 + (  # rec blocks (2 of 3)
-            (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.hd / m + d
-            + 2 * cfg.d_ff / m
-        ) / 3
-    else:
-        ff = cfg.d_ff_expert * cfg.top_k if cfg.family == "moe" else cfg.d_ff
-        per = (
-            (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.hd / m  # qkv
-            + cfg.n_heads * cfg.hd / m  # attn out
-            + 2 * ff / m  # gate/up
-            + 2 * d  # residual stream saves
-        )
-    return per * 2.0  # bf16
-
-
-def modeled_hbm_bytes(
-    cfg: ModelConfig,
-    kind: str,
-    seq: int,
-    global_batch: int,
-    *,
-    model_ways: int,
-    dp_ways: int,
-    fsdp: bool = False,
-) -> float:
-    """Per-chip HBM bytes for one step of ``kind``."""
-    n_params = cfg.n_params()
-    n_active = cfg.n_active_params()
-    p_shard = n_params / model_ways / (dp_ways if fsdp else 1)
-    eff_seq = cfg.max_target_len if cfg.is_encdec and kind != "prefill" else seq
-    tokens_chip = global_batch * eff_seq / dp_ways / max(
-        1, (model_ways if kind != "decode" else 1)
-    )
-    # sequence is model-sharded (SP) in train/prefill; decode has 1 token.
-    if kind == "decode":
-        tokens_chip = max(global_batch / dp_ways, 1.0)
-
-    n_layers = cfg.n_layers + cfg.n_encoder_layers
-
-    if kind == "train":
-        param_traffic = p_shard * (4 + 4 + 2 + 2)  # f32 r/w + bf16 w/r
-        opt_traffic = p_shard * 4 * 4  # mu, nu r+w
-        grad_traffic = p_shard * 4 * 2
-        act = tokens_chip * n_layers * _act_bytes_per_token_layer(
-            cfg, model_ways
-        ) * 2.0  # write fwd + read bwd
-        logits = tokens_chip * (cfg.vocab / model_ways) * 2 * 3
-        return param_traffic + opt_traffic + grad_traffic + act + logits
-    if kind == "prefill":
-        param_traffic = p_shard * 2  # bf16-equivalent read
-        act = tokens_chip * n_layers * _act_bytes_per_token_layer(
-            cfg, model_ways
-        )
-        return param_traffic + act
-    # decode
-    p_active_shard = n_active / model_ways
-    param_traffic = p_active_shard * 2  # bf16 read per token
-    cache = _decode_cache_bytes(cfg, seq, global_batch, dp_ways, model_ways)
-    return param_traffic + cache
-
-
-def _decode_cache_bytes(
-    cfg: ModelConfig, seq: int, global_batch: int, dp_ways: int,
-    model_ways: int,
-) -> float:
-    b_chip = max(global_batch / dp_ways, 1.0)
-    if cfg.family == "ssm":
-        dv = cfg.d_inner
-        state = (
-            cfg.ssm_n_heads * cfg.ssm_state * cfg.ssm_head_dim
-            + (cfg.ssm_conv_kernel - 1)
-            * (dv + 2 * cfg.ssm_n_groups * cfg.ssm_state)
-        )
-        shard = model_ways  # heads/channels sharded
-        return b_chip * cfg.n_layers * state / shard * 4 * 2  # f32 r+w
-    if cfg.hybrid_pattern:
-        n_rec = cfg.n_layers - cfg.n_layers // cfg.hybrid_pattern
-        n_att = cfg.n_layers // cfg.hybrid_pattern
-        w = cfg.lru_width or cfg.d_model
-        rec = b_chip * n_rec * (
-            w + (cfg.ssm_conv_kernel - 1) * w
-        ) / model_ways * 4 * 2
-        win = min(cfg.local_window, seq)
-        att = b_chip * n_att * win * cfg.n_kv_heads * cfg.hd * 2
-        return rec + att
-    cache_len = (
-        min(cfg.sliding_window, seq) if cfg.sliding_window else seq
-    )
-    kv_shard = model_ways if cfg.n_kv_heads % model_ways == 0 else 1
-    return (
-        b_chip * cfg.n_layers * cache_len * 2  # k and v
-        * cfg.n_kv_heads * cfg.hd / kv_shard * 2  # bf16
-    )
 
 
 # ---------------------------------------------------------------------------

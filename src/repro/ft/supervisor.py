@@ -7,8 +7,8 @@ pieces here implement that contract in-process:
 
 * ``Supervisor.run`` drives the step loop, checkpoints every
   ``ckpt_every`` steps, and on failure restores the last checkpoint and
-  REPLAYS from its step — with the deterministic data pipeline
-  (data/pipeline.py) the recovery is exact.
+  REPLAYS from its step — with a deterministic step function (every
+  solver step here is one) the recovery is exact.
 * ``SimulatedFailure`` + ``failure_at`` inject crashes for tests/examples
   (the CPU stand-in for a node loss). ``Supervisor.recoverable`` widens
   the checkpoint-restore trigger to real runtime errors (device loss,

@@ -5,7 +5,7 @@ interpret-mode switch for CPU validation, strategy selection (``"swc"``
 pipelined VPU, ``"swc_stream"`` explicit streaming, ``"tc"`` banded
 matrix-unit contractions, plus the compiler-managed ``"hwc"`` baseline),
 and ``"auto"`` block resolution through ``repro.tuning``, so callers
-(fusion engine, physics, models) never touch BlockSpecs.
+(fusion engine, physics, serving) never touch BlockSpecs.
 
 On the CPU backend ``interpret`` defaults to True; on TPU it defaults
 to False; any other backend raises. Override explicitly for tests.
@@ -25,7 +25,6 @@ import jax.numpy as jnp
 
 from repro.core.stencil import OperatorSet
 from repro.kernels import ref as _ref
-from repro.kernels.conv1d_depthwise import conv1d_depthwise_pallas
 from repro.kernels.emit import fused_stencil_pallas
 from repro.kernels.plan import StencilPlan, plan_stencil
 
@@ -266,59 +265,3 @@ def fused_stencil3d(
         f_padded, ops, phi, n_out, aux=aux, strategy=strategy,
         block=block, interpret=interpret,
     )
-
-
-def conv1d_depthwise(
-    x: jnp.ndarray,
-    w: jnp.ndarray,
-    *,
-    activation: str = "none",
-    block_seq: int | str | None = None,
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Fused depthwise causal conv1d (+ SiLU) — mamba2 frontend stencil.
-
-    ``block_seq=None`` (model call sites) uses 512 unless auto-tuning is
-    globally enabled (``repro.tuning.enable_auto()`` — the train/serve
-    drivers' ``--auto-tune``), in which case it resolves like ``"auto"``:
-    persistent cache first, measured tune on an eager miss.
-    """
-    if interpret is None:
-        interpret = _default_interpret()
-    if block_seq is None:
-        from repro.tuning.session import AUTO_ENABLED
-
-        block_seq = "auto" if AUTO_ENABLED else 512
-    if block_seq == "auto":
-        from repro.tuning.session import auto_block_conv1d
-
-        block_seq = auto_block_conv1d(
-            x, w, activation=activation, interpret=interpret
-        )
-    return _conv1d_depthwise_jit(
-        x, w, activation=activation, block_seq=block_seq,
-        interpret=interpret,
-    )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("activation", "block_seq", "interpret")
-)
-def _conv1d_depthwise_jit(
-    x: jnp.ndarray,
-    w: jnp.ndarray,
-    *,
-    activation: str,
-    block_seq: int,
-    interpret: bool,
-) -> jnp.ndarray:
-    b, s, c = x.shape
-    block_seq = min(block_seq, _round_up(s, 128))
-    s_pad = _round_up(s, block_seq)
-    if s_pad != s:
-        x = jnp.pad(x, ((0, 0), (0, s_pad - s), (0, 0)))
-    out = conv1d_depthwise_pallas(
-        x, w, activation=activation, block_seq=block_seq,
-        interpret=interpret,
-    )
-    return out[:, :s, :]

@@ -179,21 +179,6 @@ def fused_stencil_steps_batched(
     )(f_padded, aux)
 
 
-def conv1d_depthwise_causal(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-    """Depthwise causal 1-D convolution (mamba2 frontend stencil).
-
-    ``x``: (batch, seq, channels); ``w``: (k, channels). Output (b, s, c):
-    y[b, t, c] = Σ_{j<k} w[j, c] · x[b, t - (k-1) + j, c], zero-padded left.
-    """
-    k = w.shape[0]
-    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    seq = x.shape[1]
-    acc = jnp.zeros_like(x)
-    for j in range(k):
-        acc = acc + w[j][None, None, :].astype(x.dtype) * xp[:, j : j + seq, :]
-    return acc
-
-
 def xcorr1d_numpy(f_padded: np.ndarray, g: np.ndarray) -> np.ndarray:
     """Float64 numpy oracle-of-the-oracle (used by property tests)."""
     f_padded = np.asarray(f_padded, dtype=np.float64)

@@ -1,2 +1,2 @@
-"""Launch layer: production mesh, input specs, jitted step builders,
-multi-pod dry-run, training/serving drivers."""
+"""Launch layer: the ensemble-simulation serving loop
+(``repro.launch.serve_sim``)."""

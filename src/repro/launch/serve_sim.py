@@ -1,9 +1,8 @@
 """Ensemble simulation serving: the stencil-workload front door.
 
-``repro.launch.serve`` serves language-model decode; THIS module serves
-stencil simulations — thousands of concurrent scenarios (parameter
-sweeps, Monte-Carlo ensembles, per-user simulations) funneled through
-the batched fused-stencil engine:
+This module serves stencil simulations — thousands of concurrent
+scenarios (parameter sweeps, Monte-Carlo ensembles, per-user
+simulations) funneled through the batched fused-stencil engine:
 
 * ``SimRequest`` / ``RequestQueue`` — FIFO request intake with
   shape-bucketed draining: requests sharing (spatial shape, dtype,
@@ -120,11 +119,8 @@ def _bucket_label(key: BucketKey) -> str:
 class RequestQueue:
     """FIFO request queue with bucket-aware batch draining.
 
-    Generic over the request type: the LM example
-    (``examples/serve_batched.py``) pops one request at a time into
-    freed decode slots; ensemble serving drains plan-compatible batches
-    with :meth:`next_bucket`. Backed by a ``collections.deque`` so the
-    hot single-request pop is O(1), not ``list.pop(0)``'s O(n).
+    Ensemble serving drains plan-compatible batches with
+    :meth:`next_bucket`.
     """
 
     def __init__(self, items=()):
@@ -132,10 +128,6 @@ class RequestQueue:
 
     def push(self, item) -> None:
         self._items.append(item)
-
-    def pop(self):
-        """Oldest request, or None when empty (LM slot refill)."""
-        return self._items.popleft() if self._items else None
 
     def snapshot(self) -> list:
         """Copy of the queued items in FIFO order — the public,

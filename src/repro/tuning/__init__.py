@@ -41,17 +41,14 @@ from repro.tuning.costmodel import (  # noqa: F401
     time_candidate,
     vmem_working_set,
 )
-from repro.tuning.shapes import warm_model_kernels  # noqa: F401
 from repro.tuning.session import (  # noqa: F401
     TuningSession,
     auto_block_3d,
-    auto_block_conv1d,
     auto_block_nd,
     auto_block_xcorr1d,
     auto_fuse_nd,
     auto_strategy_nd,
     default_session,
-    enable_auto,
     fused3d_candidates,
     fused3d_key,
     fused_nd_candidates,

@@ -70,8 +70,8 @@ class TuningKey:
 
     # Kernel family: the rank-generic plan layer keys as
     # "fused_stencil1d" / "fused_stencil2d" / "fused_stencil3d"
-    # (StencilPlan.kernel_name); the standalone 1-D kernels as
-    # "xcorr1d" and "conv1d_depthwise".
+    # (StencilPlan.kernel_name); the standalone 1-D kernel as
+    # "xcorr1d".
     kernel: str
     # Strategy id from the plan layer's strategy_sid derivation — e.g.
     # "swc", "swc_stream:sy", "tc:f2:b4", "auto:sauto:fauto" — or

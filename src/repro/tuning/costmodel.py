@@ -477,8 +477,8 @@ def domain_axis_options(
 
 @dataclasses.dataclass(frozen=True)
 class Candidate1D:
-    """Block-length candidate for the 1-D kernels (xcorr, depthwise
-    conv): ``block`` elements per grid step along the streamed axis."""
+    """Block-length candidate for the 1-D cross-correlation:
+    ``block`` elements per grid step along the streamed axis."""
 
     block: int
     vmem_bytes: int
@@ -489,7 +489,6 @@ def enumerate_candidates_1d(
     n: int,
     halo: int,
     *,
-    width: int = 1,
     itemsize: int = 4,
     vmem_budget: int = VMEM_BUDGET,
     options: Sequence[int] = (256, 512, 1024, 2048, 4096, 8192),
@@ -497,13 +496,12 @@ def enumerate_candidates_1d(
     """Rank 1-D block lengths: VMEM filter, then a structural score that
     trades halo refetch + per-step pipeline overhead (favoring long
     blocks) against tail-padding waste (favoring blocks near a divisor
-    of ``n``). ``width`` is the per-element row width (channels for the
-    depthwise conv)."""
+    of ``n``)."""
     out: list[Candidate1D] = []
     for b in options:
         if b > max(n, LANE):
             continue
-        vm = (2 * (b + halo) + b) * width * itemsize
+        vm = (2 * (b + halo) + b) * itemsize
         if vm > vmem_budget:
             continue
         waste = (-(-n // b) * b - n) / n

@@ -43,19 +43,6 @@ log = logging.getLogger("repro.tuning")
 # persisted record with this still at zero.
 MEASURE_COUNT = 0
 
-# Global opt-in: when True, kernel call sites that pass no explicit block
-# (the model hot paths, e.g. mamba2's conv frontend) resolve as "auto".
-# Flipped by the train/serve drivers' --auto-tune flag.
-AUTO_ENABLED = False
-
-
-def enable_auto(on: bool = True) -> None:
-    """Globally opt model call sites with no explicit block into
-    ``"auto"`` resolution (the train/serve drivers' ``--auto-tune``)."""
-    global AUTO_ENABLED
-    AUTO_ENABLED = on
-
-
 @dataclasses.dataclass
 class TuningSession:
     """One tuning context: a cache plus the measurement protocol knobs
@@ -875,7 +862,7 @@ def lookup_fused3d(
 
 
 # ---------------------------------------------------------------------------
-# 1-D kernel glue (xcorr1d block_size="auto", conv1d block_seq="auto").
+# 1-D kernel glue (xcorr1d block_size="auto").
 # ---------------------------------------------------------------------------
 
 
@@ -922,56 +909,6 @@ def auto_block_xcorr1d(
                     f_padded, g, strategy=strategy,
                     block_size=int(cand.block), unroll=unroll,
                     interpret=interpret,
-                )
-
-            return time_candidate(
-                fn, warmup=sess.warmup, iters=sess.iters
-            )
-
-    return int(sess.tune(key, cands, measure).block)
-
-
-def auto_block_conv1d(
-    x,
-    w,
-    *,
-    activation: str,
-    interpret: bool,
-    session: TuningSession | None = None,
-) -> int:
-    """Resolve ``block_seq="auto"`` for the depthwise causal conv."""
-    sess = session if session is not None else default_session()
-    b, s, c = x.shape
-    k = w.shape[0]
-    key = TuningKey(
-        kernel="conv1d_depthwise",
-        strategy=activation or "none",
-        domain=(b, s, c),
-        radii=(k - 1,),
-        n_f=1,
-        n_out=1,
-        dtype=str(x.dtype),
-        backend=current_backend(),
-    )
-    cands = enumerate_candidates_1d(
-        s, k - 1, width=c, itemsize=x.dtype.itemsize,
-        options=(128, 256, 512, 1024, 2048),
-    )
-    if not cands:
-        return 512
-
-    measure = None
-    if _is_concrete(x) and _is_concrete(w):
-
-        def measure(cand):
-            """Median seconds for one candidate block length."""
-            from repro.kernels import ops as kops
-
-            def fn():
-                """One timed depthwise-conv call at ``cand.block``."""
-                return kops.conv1d_depthwise(
-                    x, w, activation=activation,
-                    block_seq=int(cand.block), interpret=interpret,
                 )
 
             return time_candidate(

@@ -132,41 +132,6 @@ def _warm_xcorr1d(full: bool) -> None:
         kops.xcorr1d(f, g, strategy="baseline", block_size="auto")
 
 
-def _warm_conv1d(full: bool) -> None:
-    from repro.kernels import ops as kops
-
-    rng = np.random.default_rng(0)
-    b, s, c, k = (4, 2048, 256, 4) if full else (2, 512, 64, 4)
-    x = jnp.asarray(rng.standard_normal((b, s, c)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((k, c)), jnp.float32)
-    kops.conv1d_depthwise(x, w, block_seq="auto")
-
-
-def warm_model_kernels(cfg, batch: int, seq_len: int, dtype=None) -> int:
-    """Eagerly pre-measure the kernel blocks a model's hot path will
-    request under ``--auto-tune`` (today: the mamba2 depthwise-conv
-    frontend; transformers have no Pallas stencil). Returns the number of
-    shapes warmed. Called by the train/serve drivers so the later jitted
-    step traces resolve ``"auto"`` from the cache instead of the cost
-    model. ``dtype`` defaults to the model compute dtype (``cfg.dtype``)
-    — the tuning key is dtype-specific, so warming in any other dtype
-    would never be replayed by the jitted step."""
-    if cfg.family != "ssm":
-        return 0
-    from repro.kernels import ops as kops
-    from repro.models.ssm import _dims
-
-    if dtype is None:
-        dtype = jnp.dtype(getattr(cfg, "dtype", "float32"))
-    conv_ch = _dims(cfg)[-1]
-    k = cfg.ssm_conv_kernel
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.standard_normal((batch, seq_len, conv_ch)), dtype)
-    w = jnp.asarray(rng.standard_normal((k, conv_ch)), dtype)
-    kops.conv1d_depthwise(x, w, block_seq="auto")
-    return 1
-
-
 # ---------------------------------------------------------------------------
 # Static-audit shapes (repro.analysis)
 # ---------------------------------------------------------------------------
@@ -300,5 +265,4 @@ REGISTRY: tuple[WarmEntry, ...] = (
     WarmEntry("fig13-14/mhd_swc", _warm_mhd),
     WarmEntry("fig13/mhd_swc_stream", _warm_mhd_stream),
     WarmEntry("fig07-09/xcorr1d", _warm_xcorr1d),
-    WarmEntry("mamba2/conv1d_depthwise", _warm_conv1d),
 )

@@ -1,6 +1,4 @@
-"""Core stencil-math, autotune, optimizer, and roofline-parser tests."""
-import jax
-import jax.numpy as jnp
+"""Core stencil-math, autotune, and roofline-parser tests."""
 import numpy as np
 import pytest
 
@@ -158,45 +156,3 @@ def test_roofline_terms_and_dominance():
 
 def test_machine_balance_matches_brief():
     assert rl.TPU_V5E.machine_balance(2) == pytest.approx(197e12 / 819e9)
-
-
-# --- optimizer -------------------------------------------------------------------
-
-
-def test_adamw_decreases_quadratic():
-    from repro.optim import AdamWConfig, adamw_init, adamw_update
-
-    cfg = AdamWConfig(lr_peak=0.1, warmup_steps=1, total_steps=100,
-                      weight_decay=0.0)
-    params = {"w": jnp.asarray([2.0, -3.0])}
-    state = adamw_init(params)
-
-    def loss(p):
-        return jnp.sum(p["w"] ** 2)
-
-    for _ in range(60):
-        g = jax.grad(loss)(params)
-        params, state, _ = adamw_update(cfg, g, state, params)
-    assert float(loss(params)) < 0.1
-
-
-def test_adamw_skips_decay_on_norms():
-    from repro.optim.adamw import _decays
-
-    class K:
-        def __init__(self, key):
-            self.key = key
-
-    assert _decays((K("blocks"), K("wq")))
-    assert not _decays((K("blocks"), K("ln1")))
-    assert not _decays((K("blocks"), K("A_log")))
-
-
-def test_grad_clip():
-    from repro.optim import clip_by_global_norm
-
-    g = {"a": jnp.full((4,), 10.0)}
-    clipped, norm = clip_by_global_norm(g, 1.0)
-    assert float(norm) == pytest.approx(20.0)
-    total = float(jnp.sqrt(jnp.sum(clipped["a"] ** 2)))
-    assert total == pytest.approx(1.0, rel=1e-5)

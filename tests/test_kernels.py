@@ -105,22 +105,3 @@ def test_fused3d_aux_inputs():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(expect), rtol=1e-4, atol=1e-4
     )
-
-
-# --- depthwise conv sweeps ------------------------------------------------------
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("bsck", [(1, 64, 8, 4), (3, 100, 16, 4),
-                                  (2, 257, 32, 7)])
-def test_conv1d_depthwise_sweep(dtype, bsck):
-    b, s, c, k = bsck
-    x = jnp.asarray(RNG.standard_normal((b, s, c)), dtype)
-    w = jnp.asarray(RNG.standard_normal((k, c)), dtype)
-    out = ops.conv1d_depthwise(x, w, interpret=True, block_seq=128)
-    expect = ref.conv1d_depthwise_causal(x, w)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(expect, np.float32),
-        rtol=tol, atol=tol,
-    )

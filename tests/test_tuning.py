@@ -265,23 +265,6 @@ def test_xcorr1d_auto_matches_explicit(cache_dir):
     )
 
 
-def test_conv1d_auto_matches_explicit(cache_dir):
-    from repro.kernels import ops as kops
-    from repro.kernels import ref
-
-    rng = np.random.default_rng(4)
-    x = jnp.asarray(rng.standard_normal((2, 256, 16)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((4, 16)), jnp.float32)
-    out = kops.conv1d_depthwise(x, w, block_seq="auto", interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref.conv1d_depthwise_causal(x, w)),
-        rtol=1e-5, atol=1e-5,
-    )
-    assert any(
-        k.startswith("conv1d_depthwise|") for k in TuningCache().items()
-    )
-
-
 # --- corruption quarantine (ISSUE 8) --------------------------------------------
 
 
