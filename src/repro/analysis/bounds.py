@@ -21,7 +21,13 @@ the emitter's own geometry hooks (``lowering_windows`` /
   exactly the global plane the chunk's input window calls for);
 * **sweep geometry** — at each synthetic-φ call boundary, derivative
   blocks and aux carries are extent-aligned with the independently
-  derived ``τ + 2r·(S-1-s)`` schedule.
+  derived ``τ + 2r·(S-1-s)`` schedule;
+* **zero ghosts** — a zero-ghost plan (``plan.ghost == "zero"``) is run
+  over its whole grid in order, its copies landing at ``start()``: every
+  copy box lies inside the unpadded operand, and every window element
+  the body reads holds what the zero-padded operand holds there — the
+  copied point inside the domain, a stored zero outside (element
+  provenance across the two slots and the prefetch of the next step).
 
 The shadow run also measures the VMEM working set actually staged
 (ref shapes + the observed carried intermediate), which
@@ -48,6 +54,11 @@ from repro.analysis.shadow import (
     uncovered,
 )
 from repro.kernels.plan import StencilPlan, staged_window
+
+# Window provenance of the zero-ghost audit: an element never written,
+# and an element holding a stored zero. Others hold the flat index of
+# the operand point copied there.
+_UNSET, _ZERO = -1, -2
 
 
 @dataclasses.dataclass
@@ -207,6 +218,141 @@ def _audit_pipelined(
         2 * plan.n_f * math.prod(staged_window(window))
         + 2 * aux_sz
         + mid
+        + plan.n_out * math.prod(out_tile)
+    )
+
+
+def _audit_zero_ghost(
+    plan: StencilPlan, ops: Any, findings: list[Finding],
+    observed: list[tuple[int, ...]],
+    aux_rows: tuple[int, ...] | None = None,
+) -> int:
+    """Shadow-run a zero-ghost launch over its whole grid, in the order
+    the chip runs it (z innermost), with element provenance on the
+    double-buffered window, and return the measured VMEM bytes.
+
+    Copies land when started (the next step's prefetch included) from
+    an unpadded ``f_hbm``, so strict slicing proves every copy box lies
+    inside the operand. At every read of the window, each element must
+    hold what the zero-padded operand holds at its place for the step
+    being computed: a wrong face copy shows as a misplaced point or a
+    zero where data belongs ("bounds"), a missing fill as a never-
+    written element ("uninit")."""
+    from repro.kernels import emit
+
+    sid = plan.strategy_id
+    windows = emit.lowering_windows(plan)
+    out_tile, margins = windows["out_tile"], windows["margins"]
+    for a, (g, st) in enumerate(zip(plan.grid, plan.block)):
+        if g * st != plan.interior[a]:
+            findings.append(Finding(
+                "coverage", sid,
+                f"axis {a}: grid {g} x step {st} != interior "
+                f"{plan.interior[a]}",
+            ))
+    f_shape = (plan.n_f,) + plan.interior
+    f_hbm = ShadowRef("f_hbm", f_shape, plan.dtype, initialized=True)
+    win = ShadowRef("win", (2, plan.n_f) + windows["window"], plan.dtype)
+    held = np.full(win.shape, _UNSET, np.int64)
+    where = [0, 0]  # (z, y) block of the step being computed
+
+    def box_slices(box: Box) -> tuple[slice, ...]:
+        return tuple(slice(lo, hi) for lo, hi in box)
+
+    def on_write(box: Box, value: Any) -> None:
+        ext = tuple(hi - lo for lo, hi in box)
+        if isinstance(value, ShadowArray):
+            if value.src is None or value.src[0] is not f_hbm:
+                raise AuditError(
+                    "bounds", f"window {box} filled from a value that is "
+                    "no copy of the operand",
+                )
+            pts = np.meshgrid(
+                *(np.arange(lo, hi) for lo, hi in value.src[1]),
+                indexing="ij",
+            )
+            held[box_slices(box)] = np.ravel_multi_index(
+                pts, f_shape
+            ).reshape(ext)
+            return
+        zeros = np.asarray(value)
+        if zeros.size != np.prod(ext) or np.any(zeros != 0):
+            raise AuditError(
+                "bounds", f"window {box} stored with a value other than "
+                "zeros of its extent",
+            )
+        held[box_slices(box)] = _ZERO
+
+    def on_read(box: Box) -> None:
+        (s0, s1), (f0, f1), *planes = box
+        z, y, x = (
+            np.arange(lo, hi) + base
+            for (lo, hi), base in zip(planes, (
+                where[0] * plan.block[0] - margins[0],
+                where[1] * plan.block[1] - margins[1],
+                0,
+            ))
+        )
+        pts = np.meshgrid(np.arange(f0, f1), z, y, x, indexing="ij")
+        inside = np.ones(pts[0].shape, bool)
+        for p, n in zip(pts[1:], plan.interior):
+            inside &= (p >= 0) & (p < n)
+        want = np.full(pts[0].shape, _ZERO, np.int64)
+        want[inside] = np.ravel_multi_index(
+            tuple(p[inside] for p in pts), f_shape
+        )
+        got = held[box_slices(box)].reshape(want.shape)
+        bad = np.argwhere(got != want)
+        if bad.size:
+            at = tuple(int(v) for v in bad[0])
+            raise AuditError(
+                "uninit" if got[at] == _UNSET else "bounds",
+                f"step (z {where[0]}, y {where[1]}): window slot {s0} "
+                f"element {at} of read {box} holds {int(got[at])}, the "
+                f"zero-padded operand has {int(want[at])} there",
+            )
+
+    win.write_hook = on_write
+    win.read_hook = on_read
+    if aux_rows is None:
+        aux_rows = (plan.n_aux,) if plan.n_aux else ()
+    aux = [
+        ShadowRef(f"aux{i}", (rows,) + out_tile, plan.dtype, initialized=True)
+        for i, rows in enumerate(aux_rows)
+    ]
+    chunked = plan.z_chunk < plan.block[0]
+    phis = make_synthetic_phis(
+        plan, _sweep_exts(plan) if chunked else [plan.block],
+        observed_exts=observed,
+    )
+    n_z, n_y = plan.grid[0], plan.grid[1]
+    try:
+        for t in range(n_z * n_y):
+            where[:] = t % n_z, t // n_z
+            o_ref = ShadowRef("o", (plan.n_out,) + out_tile, plan.dtype)
+            ctx = ShimContext(program_ids=(where[1], 0, where[0]))
+            with shadow_shims(ctx):
+                emit._kernel_zero_ghost(
+                    f_hbm, *aux, o_ref, win, ShimSem(), ops=ops,
+                    tile=plan.block, phi=phis[0], n_aux_refs=len(aux),
+                    z_chunk=plan.z_chunk if chunked else None,
+                    copies=emit.zero_ghost_copies(plan),
+                    blocks=(n_z, n_y), margins=margins,
+                )
+            holes = uncovered(o_ref.full_box(), o_ref.writes)
+            if holes:
+                findings.append(Finding(
+                    "coverage", sid,
+                    f"step (z {where[0]}, y {where[1]}): output tile "
+                    f"region {holes[0]} never stored",
+                ))
+    except AuditError as e:
+        findings.append(Finding(e.cls, sid, e.detail))
+
+    itemsize = np.dtype(plan.dtype).itemsize
+    return itemsize * (
+        math.prod(win.shape)
+        + 2 * plan.n_aux * math.prod(out_tile)
         + plan.n_out * math.prod(out_tile)
     )
 
@@ -400,6 +546,10 @@ def audit_plan(
     try:
         if plan.strategy == "swc_stream":
             measured = _audit_stream(exec_plan, ops, findings, observed)
+        elif plan.ghost == "zero":
+            measured = _audit_zero_ghost(
+                exec_plan, ops, findings, observed, aux_rows
+            )
         else:
             measured = _audit_pipelined(
                 exec_plan, ops, findings, observed, aux_rows

@@ -5,7 +5,7 @@ product is small — a few thousand combinations):
 
 1. **sid injectivity** — :func:`repro.kernels.plan.strategy_sid` is
    injective over the full valid axis product (strategy × rank ×
-   unroll × fuse × batch × accuracy × n_aux) *modulo the one
+   unroll × fuse × batch × accuracy × n_aux × ghost) *modulo the one
    documented alias*: accuracy 0 ("unknown") and
    :data:`~repro.kernels.plan.DEFAULT_ACCURACY` both key unmarked.
    Two combos mapping to the same sid must be identical in every other
@@ -49,16 +49,21 @@ _FUSES: tuple[Any, ...] = (1, 2, 3, "auto")
 _BATCHES = (1, 2, 4)
 _ACCURACIES = (0, 2, 4, 6, 8)
 _AUXES = (0, 1, 2)
+_GHOSTS = (None, "zero")
 
-Combo = tuple[str, int, int, Any, int, int, int]
-# (strategy, rank, unroll, fuse, batch, accuracy, n_aux)
+Combo = tuple[str, int, int, Any, int, int, int, Any]
+# (strategy, rank, unroll, fuse, batch, accuracy, n_aux, ghost)
 
 
 def _valid(c: Combo) -> bool:
     """Mirror of the plan/search-layer constraints on the axis product
     (kept independent of ``StencilPlan.__post_init__`` on purpose: the
     auditor restates the rules it is checking against)."""
-    strategy, rank, unroll, fuse, batch, _acc, n_aux = c
+    strategy, rank, unroll, fuse, batch, _acc, n_aux, ghost = c
+    if ghost == "zero" and (strategy, rank, unroll, fuse, batch) != (
+        "swc", 3, 1, 1, 1
+    ):
+        return False  # zero ghosts: rank-3, unbatched swc at depth 1
     if strategy == "swc_stream" and (rank == 1 or n_aux or unroll != 1):
         return False
     if strategy == "tc" and unroll != 1:
@@ -75,7 +80,7 @@ def _valid(c: Combo) -> bool:
 def enumerate_combos() -> Iterator[Combo]:
     for c in itertools.product(
         _STRATEGIES, _RANKS, _UNROLLS, _FUSES, _BATCHES, _ACCURACIES,
-        _AUXES,
+        _AUXES, _GHOSTS,
     ):
         if _valid(c):
             yield c
@@ -88,7 +93,8 @@ _SID_RE = re.compile(
     r"(?::f(?P<fuse>auto|\d+))?"
     r"(?::b(?P<batch>\d+))?"
     r"(?::a(?P<aux>\d+))?"
-    r"(?::o(?P<acc>\d+))?$"
+    r"(?::o(?P<acc>\d+))?"
+    r"(?::g(?P<ghost>z))?$"
 )
 
 
@@ -107,6 +113,7 @@ def parse_sid(sid: str) -> dict[str, Any] | None:
         "batch": int(m["batch"] or 1),
         "n_aux": int(m["aux"] or 0),
         "accuracy": int(m["acc"]) if m["acc"] is not None else None,
+        "ghost": "zero" if m["ghost"] else None,
     }
 
 
@@ -115,9 +122,9 @@ def _alias_ok(a: Combo, b: Combo) -> bool:
     documented accuracy alias ({0, DEFAULT_ACCURACY} key unmarked) —
     or only in rank for non-streaming strategies (rank joins the
     TuningKey via ``kernel_name``, not the sid)."""
-    sa, ra, ua, fa, ba, aa, xa = a
-    sb, rb, ub, fb, bb, ab, xb = b
-    if (sa, ua, fa, ba, xa) != (sb, ub, fb, bb, xb):
+    sa, ra, ua, fa, ba, aa, xa, ga = a
+    sb, rb, ub, fb, bb, ab, xb, gb = b
+    if (sa, ua, fa, ba, xa, ga) != (sb, ub, fb, bb, xb, gb):
         return False
     if sa == "swc_stream" and ra != rb:
         return False  # the stream letter must disambiguate ranks
@@ -133,9 +140,9 @@ def audit_sid_injectivity() -> tuple[list[Finding], int]:
     by_sid: dict[str, list[Combo]] = {}
     n = 0
     for c in enumerate_combos():
-        strategy, rank, unroll, fuse, batch, acc, n_aux = c
+        strategy, rank, unroll, fuse, batch, acc, n_aux, ghost = c
         sid = plan_mod.strategy_sid(
-            strategy, rank, unroll, fuse, batch, acc, n_aux
+            strategy, rank, unroll, fuse, batch, acc, n_aux, ghost
         )
         n += 1
         by_sid.setdefault(sid, []).append(c)
@@ -157,6 +164,7 @@ def audit_sid_injectivity() -> tuple[list[Finding], int]:
             and parsed["fuse"] == fuse
             and parsed["batch"] == batch
             and parsed["n_aux"] == n_aux
+            and parsed["ghost"] == ghost
             and (
                 parsed["accuracy"] == acc
                 if acc not in (0, DEFAULT_ACCURACY)
@@ -191,7 +199,7 @@ def _normalized_identity(plan: StencilPlan) -> tuple:
     return (
         plan.rank, plan.strategy, plan.radii, plan.interior, plan.n_f,
         plan.n_out, plan.dtype, plan.n_aux, plan.unroll,
-        plan.fuse_steps, plan.batch, acc,
+        plan.fuse_steps, plan.batch, acc, plan.ghost,
     )
 
 
@@ -239,7 +247,7 @@ def audit_record_roundtrip(
     shape = lead + (plan.n_f,) + plan.interior
     back = plan_mod.plan_from_record(
         ops, shape, plan.n_out, rec, dtype=plan.dtype,
-        n_aux=plan.n_aux,
+        n_aux=plan.n_aux, ghost=plan.ghost,
     )
     if back != plan:
         return [Finding(

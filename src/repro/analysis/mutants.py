@@ -7,8 +7,9 @@ audited modules (halo slice one element wide, streaming prologue one
 halo short, carry skewed by a plane, the pre-fix VMEM model that
 ignored unroll/aux, a strategy id that drops the batch suffix, a
 record rebuild that drops unroll, a temporal sweep with skewed margin,
-an unroll loop that skips the last sub-tile), runs the relevant audit
-on a small fixed plan set, and asserts at least one finding of the
+an unroll loop that skips the last sub-tile, a zero-ghost face copy
+one plane short), runs the relevant audit on a small fixed plan set,
+and asserts at least one finding of the
 expected class appears. Every patch is applied through the owning
 module's attribute (the auditor resolves them at call time) and always
 restored.
@@ -199,7 +200,7 @@ def _make_kernel_stream_mutant(
 
 def _vmem_working_set_legacy(
     block, radii, n_f, n_out, itemsize, fuse_steps=1, stream=False,
-    *, batch=1, unroll=1, n_aux=0,
+    *, batch=1, unroll=1, n_aux=0, ghost=None,
 ):
     """The pre-fix cost model: unroll and aux residency ignored."""
     n_f = n_f * batch
@@ -224,15 +225,30 @@ def _vmem_working_set_legacy(
 
 def _strategy_sid_no_batch(
     strategy, rank, unroll=1, fuse_steps=1, batch=1, accuracy=0,
-    n_aux=0,
+    n_aux=0, ghost=None,
 ):
     """strategy_sid that drops the ensemble suffix — batched and
     single-member plans collide."""
     from repro.kernels import plan as plan_mod
 
     return plan_mod._REAL_STRATEGY_SID(
-        strategy, rank, unroll, fuse_steps, 1, accuracy, n_aux
+        strategy, rank, unroll, fuse_steps, 1, accuracy, n_aux, ghost
     )
+
+
+def _zero_ghost_copies_face_short(plan):
+    """``emit.zero_ghost_copies`` whose low-z face copy starts one plane
+    late: the first plane of the domain is never copied, and the window
+    holds a zero where it belongs."""
+    from repro.kernels import emit
+
+    z_cases, y_cases = emit._REAL_ZERO_GHOST_COPIES(plan)
+    z_cases = tuple(
+        (first, last, off + 1, ext - 1) if first == 0 else
+        (first, last, off, ext)
+        for first, last, off, ext in z_cases
+    )
+    return z_cases, y_cases
 
 
 # ---------------------------------------------------------------------------
@@ -253,6 +269,7 @@ def _patched(module: Any, attr: str, value: Any) -> Iterator[None]:
 def _fixture_plans() -> dict[str, Any]:
     """Small fixed plans, one per audited regime."""
     ops2 = derivative_operator_set(2, accuracy=2)
+    ops3 = derivative_operator_set(3, accuracy=2)
     return {
         "ops": ops2,
         # pipelined, unrolled: interior (8, 256), block (8, 128), u2
@@ -267,15 +284,26 @@ def _fixture_plans() -> dict[str, Any]:
         "temporal": plan_stencil(
             ops2, (2, 68, 260), 2, strategy="swc", fuse_steps=2
         ),
+        # zero ghosts staged in the kernel: interior (24, 24, 128),
+        # 3 x 3 blocks, so the low-face, interior and high-face copies
+        # of z and y all run; mixed partials read the window's corners
+        "ops3": ops3,
+        "zero_ghost": plan_stencil(
+            ops3, (1, 26, 26, 130), 1, block=(8, 8, 128), ghost="zero"
+        ),
     }
 
 
+def _fixture_ops(fix: dict, which: str) -> Any:
+    return fix["ops3"] if which == "zero_ghost" else fix["ops"]
+
+
 def _audit_bounds(fix: dict, which: str) -> list[Finding]:
-    return audit_plan(fix[which], fix["ops"]).findings
+    return audit_plan(fix[which], _fixture_ops(fix, which)).findings
 
 
 def _audit_vmem(fix: dict, which: str) -> list[Finding]:
-    res = audit_plan(fix[which], fix["ops"])
+    res = audit_plan(fix[which], _fixture_ops(fix, which))
     return res.findings + check_vmem(fix[which], res.measured_vmem)
 
 
@@ -307,6 +335,12 @@ def _mutants() -> tuple[Mutant, ...]:
         plan_mod._REAL_STRATEGY_SID = plan_mod.strategy_sid
         return _patched(
             plan_mod, "strategy_sid", _strategy_sid_no_batch
+        )
+
+    def copies_patch():
+        emit._REAL_ZERO_GHOST_COPIES = emit.zero_ghost_copies
+        return _patched(
+            emit, "zero_ghost_copies", _zero_ghost_copies_face_short
         )
 
     def record_patch():
@@ -365,6 +399,13 @@ def _mutants() -> tuple[Mutant, ...]:
             lambda fix: _audit_bounds(fix, "unrolled"),
         ),
         Mutant(
+            "zero-ghost-face-short",
+            "low-z face copy starts one plane late, a zero in its place",
+            frozenset({"bounds", "uninit"}),
+            copies_patch,
+            lambda fix: _audit_bounds(fix, "zero_ghost"),
+        ),
+        Mutant(
             "vmem-model-legacy",
             "cost model ignores unroll and aux residency",
             frozenset({"vmem"}),
@@ -400,7 +441,7 @@ def run_harness() -> dict[str, dict[str, Any]]:
     fix = _fixture_plans()
     results: dict[str, dict[str, Any]] = {}
     clean: list[Finding] = []
-    for which in ("unrolled", "stream", "temporal"):
+    for which in ("unrolled", "stream", "temporal", "zero_ghost"):
         clean.extend(_audit_vmem(fix, which))
     clean.extend(_audit_keys_sid(fix))
     clean.extend(_audit_keys_roundtrip(fix))

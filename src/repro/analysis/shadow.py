@@ -205,6 +205,13 @@ class ShadowArray:
         shape[1 + axis] = ext_out
         return ShadowArray(tuple(shape), np.float32)
 
+    def shadow_zero_lanes(self, lanes: int) -> "ShadowArray":
+        """Shadow of ``emit._zero_lanes``: the block with ``lanes`` zero
+        lanes joined on each side of its last axis."""
+        return ShadowArray(
+            self.shape[:-1] + (self.shape[-1] + 2 * lanes,), self.dtype
+        )
+
     def shadow_join(self, blocks: Sequence["ShadowArray"]) -> "ShadowArray":
         """Shadow of ``emit._join_rows``: blocks joined along the row
         axis must agree on every other extent."""
@@ -221,17 +228,46 @@ class ShadowArray:
 
 
 class ShadowView:
-    """``ref.at[idx]`` — a deferred slice used as a DMA endpoint."""
+    """``ref.at[idx]`` — a deferred slice used as a DMA endpoint, or as
+    a ref a kernel body indexes further (``view[idx2]`` reads the
+    parent ref at the composed index, strictly)."""
 
     def __init__(self, ref: "ShadowRef", idx: Any):
         self.ref = ref
         self.idx = idx
+
+    @property
+    def shape(self) -> tuple[int, ...]:
+        box, keep = normalize_index(self.idx, self.ref.shape, "view")
+        return tuple(e for e, k in zip(box_extents(box), keep) if k)
+
+    def _compose(self, idx: Any) -> tuple:
+        """The parent index ``idx`` on this view selects."""
+        box, keep = normalize_index(self.idx, self.ref.shape, "view")
+        inner, inner_keep = normalize_index(
+            idx, self.shape, f"view of {self.ref.name}"
+        )
+        parts = iter(zip(inner, inner_keep))
+        out: list[Any] = []
+        for (lo, _), k in zip(box, keep):
+            if not k:
+                out.append(lo)
+                continue
+            (ilo, ihi), ik = next(parts)
+            out.append(slice(lo + ilo, lo + ihi) if ik else lo + ilo)
+        return tuple(out)
 
     def read(self) -> ShadowArray:
         return self.ref.read(self.idx)
 
     def write(self, value: Any) -> None:
         self.ref.write(self.idx, value)
+
+    def __getitem__(self, idx: Any) -> ShadowArray:
+        return self.ref.read(self._compose(idx))
+
+    def __setitem__(self, idx: Any, value: Any) -> None:
+        self.ref.write(self._compose(idx), value)
 
 
 class _AtIndexer:

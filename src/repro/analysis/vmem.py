@@ -44,6 +44,7 @@ def model_vmem(plan: StencilPlan) -> int:
         batch=plan.batch,
         unroll=plan.unroll,
         n_aux=plan.n_aux,
+        ghost=plan.ghost,
     )
 
 

@@ -266,6 +266,17 @@ class FusedStencilOp:
             self.boundary_mode, self.ops.ndim
         )
 
+    @property
+    def ghost_offer(self) -> str | None:
+        """``"zero"`` when every face is a zero Dirichlet wall and no
+        boundary-weight blend follows: the kernel may then fill the
+        ghost cells itself (``StencilPlan.ghost``). ``None`` otherwise."""
+        if self.boundary_weights or any(
+            m != "dirichlet" for m in self.boundary_modes
+        ):
+            return None
+        return "zero"
+
     def lowering_plan(
         self,
         interior_shape: Sequence[int],
@@ -273,14 +284,15 @@ class FusedStencilOp:
         n_aux: int = 0,
         dtype: str = "float32",
     ):
-        """The :class:`~repro.kernels.plan.StencilPlan` this op's
-        ``apply_padded`` lowers for an (unpadded) ``interior_shape``
-        field stack — ``(n_f, *spatial)`` or the batched
-        ``(batch, n_f, *spatial)``. ``None`` for the hwc regime (no
-        Pallas plan). Requires every lowering decision to be concrete
-        (``resolved()`` first) — the static auditor
-        (``repro.analysis``) drives this to audit exactly the plan a
-        call site will launch, without running it.
+        """The :class:`~repro.kernels.plan.StencilPlan` a call of this
+        op lowers for an (unpadded) ``interior_shape`` field stack —
+        ``(n_f, *spatial)`` or the batched ``(batch, n_f, *spatial)``:
+        a zero-ghost plan where :attr:`ghost_offer` allows and the plan
+        admits it, else the plan ``apply_padded`` lowers. ``None`` for
+        the hwc regime (no Pallas plan). Requires every lowering
+        decision to be concrete (``resolved()`` first) — the static
+        auditor (``repro.analysis``) drives this to audit exactly the
+        plan a call site will launch, without running it.
         """
         depth = self._depth_or_none()
         if depth is None or self.strategy == "auto":
@@ -300,7 +312,7 @@ class FusedStencilOp:
         return kops.plan_for_nd(
             self.ops, padded, self.n_out, aux_shape=aux_shape,
             strategy=self.strategy, block=self.block, dtype=dtype,
-            fuse_steps=depth,
+            fuse_steps=depth, ghost=self.ghost_offer,
         )
 
     # -- single device ------------------------------------------------------
@@ -406,9 +418,18 @@ class FusedStencilOp:
         ``f`` is (n_f, *spatial), or (batch, n_f, *spatial) for an
         ensemble stack — the extra leading axis is detected by rank and
         threaded through padding and the batched kernel lowering
-        (``aux`` then carries the same leading axis)."""
+        (``aux`` then carries the same leading axis).
+
+        Where the plan stages zero ghost cells itself
+        (:meth:`_zero_ghost_plan`), ``f`` goes to the kernel unpadded
+        and no padded copy is made."""
         if self.needs_resolution:
             return self.resolved(f, aux)(f, aux)
+        plan = self._zero_ghost_plan(f, aux)
+        if plan is not None:
+            return kops.fused_stencil_plan(
+                f, self.ops, self.phi, plan, aux=aux
+            )
         depth = int(self.fuse_steps)
         rads = self.radius_per_axis
         modes = self.boundary_modes
@@ -429,6 +450,23 @@ class FusedStencilOp:
         if self.boundary_weights and any(m != "periodic" for m in modes):
             out = self._blend_boundary_weights(f, out, aux, lead)
         return out
+
+    def _zero_ghost_plan(self, f: jnp.ndarray, aux: kref.Aux):
+        """The zero-ghost plan a call on ``f`` launches, or ``None``
+        where the call pads: the op offers zero ghosts
+        (:attr:`ghost_offer`), its Pallas ``swc`` block is concrete, and
+        the planner admits the plan (rank 3, unbatched, depth 1, a tile
+        spanning x — ``repro.kernels.plan.zero_ghost_fits``)."""
+        if (
+            self.ghost_offer is None
+            or self.strategy != "swc"
+            or isinstance(self.block, str)
+        ):
+            return None
+        row_axis = f.ndim - self.ops.ndim - 1  # 1 for an ensemble stack
+        rows = sum(a.shape[row_axis] for a in jax.tree.leaves(aux))
+        plan = self.lowering_plan(f.shape, n_aux=rows, dtype=str(f.dtype))
+        return plan if plan.ghost == "zero" else None
 
     def _blend_boundary_weights(
         self,

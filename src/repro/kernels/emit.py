@@ -45,6 +45,12 @@ Strategies (paper Sec. 4.4, Figs. 4-5, on the TPU target):
   :func:`_temporal_sweeps` with the matmul derivs; the batch axis
   composes for free (members are extra rows of the contraction).
 
+A ``swc`` plan with ``ghost="zero"`` (rank 3, depth 1, a tile spanning
+the x row) takes its operand unpadded: :func:`_kernel_zero_ghost`
+copies each window's part inside the domain into a double-buffered VMEM
+slot itself and fills the zero ghost cells there, so the caller makes
+no padded copy; the body is the pipelined one.
+
 The HWC ("let the compiler manage residency") strategy lives in
 ``repro.kernels.ref`` as pure jnp.
 
@@ -77,6 +83,8 @@ from repro.kernels.plan import (
     StencilPlan,
     staged_window,
     tc_axis_groups,
+    zero_ghost_margins,
+    zero_ghost_window,
 )
 
 _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
@@ -90,7 +98,9 @@ def _block_derivs(
 ) -> dict[str, jnp.ndarray]:
     """Evaluate every operator over a VMEM-resident block of any rank.
 
-    ``fblk``: (n_f, *(τ_a + 2r_a)). Static slices per tap — unrolled at
+    ``fblk``: (n_f, *(τ_a + 2r_a)). ``radii`` is where output point 0
+    sits in ``fblk`` on each axis: the halo radius of a window that
+    starts at its first ghost cell. Static slices per tap — unrolled at
     trace time (stencil point-wise unrolling)."""
     rank = len(tile)
     out: dict[str, jnp.ndarray] = {}
@@ -237,6 +247,27 @@ def _join_rows(blocks):
     return jnp.concatenate(blocks, axis=0)
 
 
+def _zero_lanes(blk, lanes: int):
+    """``blk`` with ``lanes`` zero lanes joined on each side of x, in
+    registers: the x ghost cells of a zero-ghost window. ``lanes`` is a
+    whole number of lane tiles, so the join moves no data. Like
+    :func:`_join_rows`, dispatches to the static auditor's shadow
+    arrays (``shadow_zero_lanes``)."""
+    shadow = getattr(blk, "shadow_zero_lanes", None)
+    if shadow is not None:
+        return shadow(lanes)
+    zeros = jnp.zeros(blk.shape[:-1] + (lanes,), blk.dtype)
+    return jnp.concatenate([zeros, blk, zeros], axis=-1)
+
+
+def _block_derivs_zero_lanes(fblk, ops, origin, tile):
+    """:func:`_block_derivs` over a zero-ghost window: the staged rows
+    joined with their zero x ghosts, every tap read at ``origin`` (where
+    output point 0 sits, :func:`~repro.kernels.plan.zero_ghost_margins`)
+    plus its offset."""
+    return _block_derivs(_zero_lanes(fblk, origin[-1]), ops, origin, tile)
+
+
 def _z_loop(n_planes: int, chunk: int, body) -> None:
     """Call ``body(z0)`` over chunks of ``chunk`` planes covering
     ``n_planes`` in one ``fori_loop``. When ``chunk`` does not divide
@@ -300,6 +331,114 @@ def _kernel_pipelined(
             o_ref[...] = val
         else:
             o_ref[..., e * tx : (e + 1) * tx] = val
+
+
+def zero_ghost_copies(
+    plan: StencilPlan,
+) -> tuple[tuple[tuple[int, int, int, int], ...], ...]:
+    """The static copies of a zero-ghost launch, on z and on y: per
+    axis, the cases ``(first, last, offset, extent)`` — the blocks
+    ``first..last`` of that axis all copy ``extent`` planes (rows) of
+    the operand into the window at ``offset``, the window's part inside
+    the domain. Blocks at a face copy less; the window's rest is zeros.
+
+    Shared by the emitter and the static auditor
+    (``repro.analysis.bounds``), like :func:`lowering_windows`."""
+    margins = zero_ghost_margins(plan.radii)
+    cases = []
+    for a in (0, 1):
+        t, g, n = plan.block[a], margins[a], plan.interior[a]
+        spans: dict[tuple[int, int], tuple[int, int]] = {}
+        for b in range(n // t):
+            lo, hi = max(0, b * t - g), min(n, b * t + t + g)
+            shape = (lo - (b * t - g), hi - lo)
+            spans[shape] = (spans.get(shape, (b,))[0], b)
+        cases.append(tuple(
+            (first, last, off, ext)
+            for (off, ext), (first, last) in spans.items()
+        ))
+    return tuple(cases)
+
+
+def _kernel_zero_ghost(
+    f_hbm, *rest, ops, tile, phi, n_aux_refs, z_chunk, copies, blocks,
+    margins,
+):
+    """Pipelined kernel with zero ghost cells staged in VMEM (rank 3).
+
+    ``f_hbm`` is the UNPADDED operand, left in HBM; ``rest`` is
+    (*aux_refs, o_ref, win, sem). Each grid step copies its window's
+    part inside the domain (:func:`zero_ghost_copies`, one static copy
+    per face case, picked with ``pl.when``) into one slot of the
+    double-buffered ``win`` — the copy for the next grid step (z
+    innermost) starts before this one computes — and stores zeros over
+    the slot's rest: the ghost planes and rows. The x ghosts are zero
+    lanes joined in registers (:func:`_block_derivs_zero_lanes`). The
+    body is :func:`_kernel_pipelined`'s, reading every tap at the
+    window's margins (:func:`~repro.kernels.plan.zero_ghost_margins`)
+    plus its offset: the same taps in the same order as over a padded
+    operand. ``blocks`` is the grid's (z, y) extent.
+    """
+    aux_refs, o_ref = rest[:n_aux_refs], rest[n_aux_refs]
+    win, sem = rest[n_aux_refs + 1:]
+    n_z, n_y = blocks
+    j, i = pl.program_id(0), pl.program_id(2)
+    t = j * n_z + i
+    slot = t % 2
+    wrap = i + 1 == n_z
+    nxt = (jnp.where(wrap, 0, i + 1), jnp.where(wrap, j + 1, j))
+
+    def each_copy(bz, by, fn):
+        for zc in copies[0]:
+            for yc in copies[1]:
+                pl.when(
+                    (bz >= zc[0]) & (bz <= zc[1])
+                    & (by >= yc[0]) & (by <= yc[1])
+                )(functools.partial(fn, zc, yc))
+
+    def copy(bz, by, s, zc, yc):
+        (_, _, oz, ez), (_, _, oy, ey) = zc, yc
+        return pltpu.make_async_copy(
+            f_hbm.at[
+                :,
+                pl.ds(bz * tile[0] - margins[0] + oz, ez),
+                pl.ds(by * tile[1] - margins[1] + oy, ey),
+            ],
+            win.at[s, :, pl.ds(oz, ez), pl.ds(oy, ey)],
+            sem.at[s],
+        )
+
+    def land(zc, yc):
+        copy(i, j, slot, zc, yc).wait()
+        (_, _, oz, ez), (_, _, oy, ey) = zc, yc
+        n_f, wz, wy, wx = win.shape[1:]
+        for lo, ext in ((0, oz), (oz + ez, wz - oz - ez)):
+            if ext:
+                win[slot, :, pl.ds(lo, ext)] = jnp.zeros(
+                    (n_f, ext, wy, wx), win.dtype
+                )
+        for lo, ext in ((0, oy), (oy + ey, wy - oy - ey)):
+            if ext:
+                win[slot, :, pl.ds(oz, ez), pl.ds(lo, ext)] = jnp.zeros(
+                    (n_f, ez, ext, wx), win.dtype
+                )
+
+    @pl.when(t == 0)
+    def _():
+        each_copy(0, 0, lambda zc, yc: copy(0, 0, 0, zc, yc).start())
+
+    @pl.when(t + 1 < n_z * n_y)
+    def _():
+        each_copy(
+            *nxt, lambda zc, yc: copy(*nxt, 1 - slot, zc, yc).start()
+        )
+
+    each_copy(i, j, land)
+    _kernel_pipelined(
+        win.at[slot], *aux_refs, o_ref, ops=ops, radii=margins,
+        tile=tile, phi=phi, unroll=1, n_aux_refs=n_aux_refs,
+        derivs_fn=_block_derivs_zero_lanes, z_chunk=z_chunk,
+    )
 
 
 def _kernel_tc(f_ref, *rest, ops, radii, tile, phi, n_aux_refs):
@@ -507,13 +646,21 @@ def lowering_windows(plan: StencilPlan) -> dict[str, tuple[int, ...]]:
     by ``r·(S-1)`` per axis at temporal depth ``S > 1``; ``mid`` — the
     VMEM scratch a z-chunked temporal kernel keeps its intermediate
     generation in (``tile + 2r``; ``None`` unless ``plan.z_chunk`` is
-    below the z tile at depth > 1).
+    below the z tile at depth > 1); ``margins`` — where output point 0
+    sits in a zero-ghost window (``None`` for other plans).
+
+    A zero-ghost plan's ``window`` is the slot its kernel stages
+    (:func:`~repro.kernels.plan.zero_ghost_window`).
     """
     radii, tile = plan.radii, plan.block
     window = tuple(
         (plan.x_step if a == plan.rank - 1 else tile[a]) + 2 * h
         for a, h in enumerate(plan.halo)
     )
+    margins = None
+    if plan.ghost == "zero":
+        window = zero_ghost_window(tile, radii)
+        margins = zero_ghost_margins(radii)
     out_tile = tile[:-1] + (plan.x_step,)
     aux_window: tuple[int, ...] | None = None
     if plan.n_aux:
@@ -531,7 +678,7 @@ def lowering_windows(plan: StencilPlan) -> dict[str, tuple[int, ...]]:
         )
     return {
         "window": window, "out_tile": out_tile, "aux_window": aux_window,
-        "mid": mid,
+        "mid": mid, "margins": margins,
     }
 
 
@@ -621,6 +768,10 @@ def fused_stencil_pallas(
     a sequence of ``fuse_steps`` callables (one per fused sweep).
     Returns (n_out, *interior).
 
+    A zero-ghost plan (``plan.ghost == "zero"``) takes ``f_padded``
+    UNPADDED, (n_f, *interior): its kernel fills the zero ghost cells
+    itself (:func:`_fused_zero_ghost`).
+
     When ``plan.batch > 1`` the operands grow a leading ensemble axis —
     ``f_padded`` (batch, n_f, *padded), ``aux`` (batch, n_aux, ...) —
     and one kernel walks all members per block (member-major field
@@ -654,6 +805,10 @@ def fused_stencil_pallas(
     if plan.strategy == "swc_stream":
         return _fused_stream(
             f_padded, ops, phis, plan, interpret=interpret
+        )
+    if plan.ghost == "zero":
+        return _fused_zero_ghost(
+            f_padded, ops, phis[0], plan, aux=aux_ops, interpret=interpret
         )
 
     radii, tile = plan.radii, plan.block
@@ -723,6 +878,54 @@ def fused_stencil_pallas(
         interpret=interpret,
         name=name,
     )(*operands)
+
+
+def _fused_zero_ghost(
+    f, ops, phi, plan: StencilPlan, *, aux, interpret: bool = False
+):
+    """Lower a zero-ghost plan: ``f`` is the unpadded (n_f, *interior)
+    operand, handed to :func:`_kernel_zero_ghost` whole and in HBM
+    (``pl.ANY``) — one operand, copied window by window by the kernel.
+    The aux operands and the output keep the pipelined path's halo-free
+    BlockSpecs. The grid runs in order ("arbitrary"), since each step
+    starts the copy for the next."""
+    if tuple(f.shape) != (plan.n_f,) + plan.interior:
+        raise ValueError(
+            f"a zero-ghost plan takes the unpadded operand "
+            f"{(plan.n_f,) + plan.interior}, got shape {f.shape}"
+        )
+    windows = lowering_windows(plan)
+    out_tile = windows["out_tile"]
+    grid, _, tile_map = _grid_and_maps(plan)
+    z_chunk = plan.z_chunk
+    kernel = functools.partial(
+        _kernel_zero_ghost, ops=ops, tile=plan.block, phi=phi,
+        n_aux_refs=len(aux),
+        z_chunk=z_chunk if z_chunk < plan.block[0] else None,
+        copies=zero_ghost_copies(plan), blocks=(grid[2], grid[0]),
+        margins=windows["margins"],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] + [
+            pl.BlockSpec((a.shape[0],) + out_tile, tile_map) for a in aux
+        ],
+        out_specs=pl.BlockSpec((plan.n_out,) + out_tile, tile_map),
+        out_shape=jax.ShapeDtypeStruct(
+            (plan.n_out,) + plan.interior, f.dtype
+        ),
+        scratch_shapes=[
+            pltpu.VMEM((2, plan.n_f) + windows["window"], f.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+            dimension_semantics=("arbitrary",) * 3,
+        ),
+        interpret=interpret,
+        name="stencil_pipelined",
+    )(f, *aux)
 
 
 # ---------------------------------------------------------------------------

@@ -211,13 +211,17 @@ def plan_for_nd(
     dtype: str = "float32",
     unroll: int = 1,
     fuse_steps: int = 1,
+    ghost: str | None = None,
 ) -> StencilPlan | None:
     """The :class:`StencilPlan` a :func:`fused_stencil_nd` call with
     these arguments lowers through — the ONE construction shared by the
     dispatch above and the static auditor (``repro.analysis``), so the
     audited plan can never diverge from the launched one. ``None`` for
     ``strategy="hwc"`` (no Pallas plan); ``block`` must be concrete
-    (resolve ``"auto"`` through the tuning session first)."""
+    (resolve ``"auto"`` through the tuning session first). ``ghost`` is
+    the boundary's offer of zero ghost cells
+    (:func:`~repro.kernels.plan.plan_stencil`); a plan that takes it is
+    launched with :func:`fused_stencil_plan`."""
     if strategy == "hwc":
         return None
     if isinstance(block, str):
@@ -232,7 +236,26 @@ def plan_for_nd(
     return plan_stencil(
         ops, padded_shape, n_out, strategy=strategy, block=block,
         dtype=dtype, n_aux=n_aux, unroll=unroll,
-        fuse_steps=fuse_steps,
+        fuse_steps=fuse_steps, ghost=ghost,
+    )
+
+
+def fused_stencil_plan(
+    f: jnp.ndarray,
+    ops: OperatorSet,
+    phi: Callable[..., jnp.ndarray],
+    plan: StencilPlan,
+    *,
+    aux: _ref.Aux = None,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """Launch a concrete Pallas ``plan`` (from :func:`plan_for_nd`) on
+    ``f``: padded as the plan's radii and depth say, or unpadded for a
+    zero-ghost plan, whose kernel fills the ghost cells itself."""
+    if interpret is None:
+        interpret = _default_interpret()
+    return fused_stencil_pallas(
+        f, ops, phi, plan, aux=aux, interpret=interpret
     )
 
 

@@ -21,6 +21,7 @@ blocks follow the same order, e.g. (τz, τy, τx) at rank 3.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import TYPE_CHECKING, Sequence
 
@@ -32,6 +33,12 @@ if TYPE_CHECKING:
     from repro.tuning.cache import TuningKey, TuningRecord
 
 STRATEGIES = ("swc", "swc_stream", "tc")
+
+# How a plan's ghost cells reach the kernel: ``None`` — from a padded
+# operand the caller built; ``"zero"`` — the kernel stages its halo
+# window from the unpadded operand and fills the ghost cells with zeros
+# in VMEM (:func:`zero_ghost_fits` says which plans can).
+GHOSTS = (None, "zero")
 
 # The tc (matrix-unit) regime contracts each axis of the φ derivative
 # sequence against a banded coefficient matrix of shape
@@ -116,6 +123,50 @@ def tpu_tile_ok(block: Sequence[int], interior: Sequence[int]) -> bool:
     )
 
 
+def zero_ghost_margins(radii: Sequence[int]) -> tuple[int, ...]:
+    """Margins of a zero-ghost plan's window around its tile, per axis:
+    the radius on the leading axis (a DMA may start at any plane), the
+    radius rounded up to whole sublane groups on y and to whole lane
+    tiles on x, so every copy lands tile-aligned. The y margin is
+    staged in VMEM; the x margin is zero lanes the kernel body joins to
+    the staged row. Output point 0 sits at these offsets in the window
+    the body reads."""
+    rz, ry, rx = radii
+    return rz, tile_aligned(ry, SUBLANE), tile_aligned(rx, LANE)
+
+
+def zero_ghost_window(
+    block: Sequence[int], radii: Sequence[int]
+) -> tuple[int, int, int]:
+    """Extents a zero-ghost plan stages per grid step: the tile widened
+    by :func:`zero_ghost_margins` on z and y, and the x row as it is."""
+    gz, gy, _ = zero_ghost_margins(radii)
+    tz, ty, tx = block
+    return tz + 2 * gz, ty + 2 * gy, tx
+
+
+def zero_ghost_fits(
+    rank: int,
+    strategy: str,
+    batch: int,
+    unroll: int,
+    fuse_steps: int,
+    block: Sequence[int],
+    interior: Sequence[int],
+) -> bool:
+    """Whether a plan can stage zero ghost cells in its kernel: a
+    rank-3, unbatched ``swc`` plan of depth 1 without element-wise
+    unrolling, whose tile spans the whole x row, with y tiles of whole
+    sublane groups and a row of whole lane tiles (so every copy of its
+    window is tile-aligned)."""
+    return (
+        (rank, strategy, batch, unroll, fuse_steps) == (3, "swc", 1, 1, 1)
+        and block[-1] == interior[-1]
+        and interior[-1] % LANE == 0
+        and block[1] % SUBLANE == 0
+    )
+
+
 def largest_divisor_leq(n: int, cap: int) -> int:
     """Largest divisor of ``n`` that is ≤ ``cap`` (≥ 1)."""
     for t in range(min(cap, n), 0, -1):
@@ -132,18 +183,22 @@ def _staged_elements(
     fuse_steps: int = 1,
     unroll: int = 1,
     n_aux: int = 0,
+    ghost: str | None = None,
 ) -> tuple[int, int, int, int]:
     """Elements one grid step of the pipelined lowering holds in VMEM:
     ``(inp, aux, mid, out)`` — the input halo window and the aux blocks
     at the extents Mosaic stages them (:func:`staged_window`), one
     intermediate field generation at temporal depth > 1, and the output
     tile. A depth-1 aux operand is a halo-free output-shaped tile; at
-    depth S it is the ``r·(S-1)``-widened window. The shapes mirror
-    ``emit.lowering_windows``; :func:`vmem_working_set` and
-    :attr:`StencilPlan.staged_per_output` both count from here."""
+    depth S it is the ``r·(S-1)``-widened window. A zero-ghost plan
+    stages :func:`zero_ghost_window` instead of the halo window. The
+    shapes mirror ``emit.lowering_windows``; :func:`vmem_working_set`
+    and :attr:`StencilPlan.staged_per_output` both count from here."""
     last = len(block) - 1
     steps = tuple(t * unroll if a == last else t for a, t in enumerate(block))
     halo_win = tuple(s + 2 * r * fuse_steps for s, r in zip(steps, radii))
+    if ghost == "zero":
+        halo_win = zero_ghost_window(block, radii)
     carry_win = tuple(
         t + 2 * r * (fuse_steps - 1) for t, r in zip(block, radii)
     )
@@ -168,6 +223,7 @@ def vmem_working_set(
     batch: int = 1,
     unroll: int = 1,
     n_aux: int = 0,
+    ghost: str | None = None,
 ) -> int:
     """VMEM footprint of one block, any rank — the ONE working-set
     formula shared by the default-tile rule (:func:`default_block`), the
@@ -202,6 +258,9 @@ def vmem_working_set(
     ``stream=True``. The shapes here mirror
     ``emit.lowering_windows``/``emit.stream_extents`` — the fidelity
     contract ``repro.analysis.vmem`` checks per lowerable plan.
+
+    ``ghost="zero"`` counts a zero-ghost plan's window
+    (:func:`zero_ghost_window`), which its kernel double-buffers itself.
     """
     if batch < 1:
         raise ValueError(f"batch must be >= 1, got {batch}")
@@ -221,7 +280,7 @@ def vmem_working_set(
         pf = n_f * block[0] * math.prod(cross)
         return (work + 2 * pf + mid + out) * itemsize
     inp, aux, mid, out = _staged_elements(
-        block, radii, n_f, n_out, fuse_steps, unroll, n_aux
+        block, radii, n_f, n_out, fuse_steps, unroll, n_aux, ghost
     )
     # Pallas double-buffers pipelined input blocks: 2x input (and aux).
     return (2 * inp + 2 * aux + mid + out) * itemsize
@@ -315,12 +374,13 @@ def staged_bytes_per_output(
     fuse_steps: int = 1,
     n_aux: int = 0,
     unroll: int = 1,
+    ghost: str | None = None,
 ) -> float:
     """Bytes a pipelined launch stages per output point: the input
     window and aux blocks at their staged extents plus the output tile,
     over the points of one grid step (:func:`_staged_elements`)."""
     inp, aux, _, out = _staged_elements(
-        block, radii, n_f, n_out, fuse_steps, unroll, n_aux
+        block, radii, n_f, n_out, fuse_steps, unroll, n_aux, ghost
     )
     return (inp + aux + out) * itemsize / (math.prod(block) * unroll)
 
@@ -334,6 +394,7 @@ def default_block(
     fuse_steps: int = 1,
     n_aux: int = 0,
     taps: int = 1,
+    ghost: str | None = None,
 ) -> tuple[int, int, int] | None:
     """The default tile of a rank-3, unbatched ``swc`` plan, derived
     from its shape and its ``taps`` per field and sweep.
@@ -351,7 +412,10 @@ def default_block(
     computes (``n_f · taps ·`` :func:`vreg_sweeps_per_output`) at
     :data:`VREG_TAP_BYTES` each. Ties go to fewer staged bytes, then to
     fewer grid steps, then to the smaller working set. ``None`` when no
-    candidate fits."""
+    candidate fits.
+
+    ``ghost="zero"`` derives the tile of a zero-ghost plan: only tiles
+    :func:`zero_ghost_fits` admits, counted at their zero-ghost window."""
     nz, ny, nx = (int(n) for n in interior)
     best = None
     for blk in (
@@ -360,16 +424,22 @@ def default_block(
         for y in _divisors(ny, SUBLANE)
         for x in _divisors(nx, LANE)
     ):
+        if ghost == "zero" and not zero_ghost_fits(
+            3, "swc", 1, 1, fuse_steps, blk, interior
+        ):
+            continue
         chunk = body_z_chunk(blk, radii, fuse_steps, n_aux)
         if chunk * plane_vregs(blk, radii, fuse_steps) > BODY_VREGS:
             continue
         vmem = vmem_working_set(
-            blk, radii, n_f, n_out, itemsize, fuse_steps, n_aux=n_aux
+            blk, radii, n_f, n_out, itemsize, fuse_steps, n_aux=n_aux,
+            ghost=ghost,
         )
         if vmem > VMEM_BUDGET:
             continue
         staged = staged_bytes_per_output(
-            blk, radii, n_f, n_out, itemsize, fuse_steps, n_aux
+            blk, radii, n_f, n_out, itemsize, fuse_steps, n_aux,
+            ghost=ghost,
         )
         compute = (
             n_f * taps * VREG_TAP_BYTES
@@ -444,10 +514,11 @@ def strategy_sid(
     batch: int = 1,
     accuracy: int = 0,
     n_aux: int = 0,
+    ghost: str | None = None,
 ) -> str:
     """Canonical strategy-id derivation — the ONE place the stream
-    axis, unroll factor, temporal depth, ensemble batch extent and
-    operator accuracy order join the cache key.
+    axis, unroll factor, temporal depth, ensemble batch extent,
+    operator accuracy order and ghost staging join the cache key.
 
     Used by both :attr:`StencilPlan.strategy_id` and the tuning layer's
     key mirror (``repro.tuning.session.fused_nd_key``), so the two can
@@ -483,6 +554,11 @@ def strategy_sid(
     block per grid step), so a block tuned without the aux residency
     must never be replayed for a call that carries it. Aux-free plans
     key unmarked — the legacy form every pre-existing record uses.
+
+    ``ghost="zero"`` appends ``:gz``: a zero-ghost plan stages another
+    window than the padded plan of the same tile, so their tuning and
+    compile-cache records never mix. Plans fed a padded operand key
+    unmarked.
     """
     sid = strategy
     if strategy == "swc_stream":
@@ -501,6 +577,8 @@ def strategy_sid(
         sid += f":a{n_aux}"
     if accuracy not in (0, DEFAULT_ACCURACY):
         sid += f":o{accuracy}"
+    if ghost == "zero":
+        sid += ":gz"
     return sid
 
 
@@ -533,6 +611,10 @@ class StencilPlan:
     it composes with ``fuse_steps``, ``batch`` and aux inputs, requires
     dtype float32/bfloat16 and ``unroll=1``, and caps tiles at
     ``TC_MAX_TILE`` per axis (see :func:`tc_axis_groups`).
+
+    ``ghost="zero"`` marks a plan whose kernel takes the UNPADDED
+    operand and fills its zero ghost cells in VMEM; only plans
+    :func:`zero_ghost_fits` admits carry it.
 
     Raises:
         ValueError: from ``__post_init__`` for any inconsistent
@@ -572,6 +654,10 @@ class StencilPlan:
     # the OperatorSpec metadata the weight generator attaches; joins
     # strategy_id as :o{A} for non-default orders (see strategy_sid).
     accuracy: int = 0
+    # "zero": the kernel stages its window from the unpadded operand and
+    # fills the ghost cells with zeros in VMEM (see GHOSTS); joins
+    # strategy_id as :gz. None: the operand arrives padded.
+    ghost: str | None = None
 
     def __post_init__(self) -> None:
         if self.accuracy < 0 or self.accuracy % 2:
@@ -663,6 +749,19 @@ class StencilPlan:
                         " — shrink fuse_steps/block[0], grow the "
                         "domain, or use strategy='swc'"
                     )
+        if self.ghost not in GHOSTS:
+            raise ValueError(f"ghost {self.ghost!r} not in {GHOSTS}")
+        if self.ghost == "zero" and not zero_ghost_fits(
+            self.rank, self.strategy, self.batch, self.unroll,
+            self.fuse_steps, self.block, self.interior,
+        ):
+            raise ValueError(
+                "zero ghosts are staged in the kernel only by a rank-3, "
+                "unbatched swc plan of depth 1 without unrolling whose "
+                "tile spans the x row in whole lane tiles and y in whole "
+                f"sublane groups; got {self.strategy_id} with block "
+                f"{self.block} over {self.interior}"
+            )
         step = self.x_step
         for a in range(self.rank):
             t = self.block[a] if a < self.rank - 1 else step
@@ -732,7 +831,7 @@ class StencilPlan:
         return staged_bytes_per_output(
             self.block, self.radii, self.n_f, self.n_out,
             np.dtype(self.dtype).itemsize, self.fuse_steps, self.n_aux,
-            self.unroll,
+            self.unroll, self.ghost,
         )
 
     # -- serialization (the tuning layer keys on this) ----------------------
@@ -750,11 +849,11 @@ class StencilPlan:
         — depth-1 and depth-2 plans cache separately, a y-streaming
         rank-2 plan (``swc_stream:sy``) never collides with a pipelined
         one, a B-member ensemble plan keys as ``:b{B}``, an aux-
-        carrying plan as ``:a{N}``, and a non-default operator order as
-        ``:o{A}``."""
+        carrying plan as ``:a{N}``, a non-default operator order as
+        ``:o{A}``, and a zero-ghost plan as ``:gz``."""
         return strategy_sid(
             self.strategy, self.rank, self.unroll, self.fuse_steps,
-            self.batch, self.accuracy, self.n_aux,
+            self.batch, self.accuracy, self.n_aux, self.ghost,
         )
 
     def tuning_key(self, backend: str | None = None) -> TuningKey:
@@ -787,6 +886,7 @@ def plan_stencil(
     fuse_steps: int = 1,
     batch: int | None = None,
     accuracy: int | None = None,
+    ghost: str | None = None,
 ) -> StencilPlan:
     """Lower a fused-stencil problem to a :class:`StencilPlan`.
 
@@ -812,7 +912,17 @@ def plan_stencil(
     ``accuracy`` defaults to the operator set's own finite-difference
     order (the OperatorSpec metadata attached by the weight generator;
     0 for hand-built tap sets), keying the plan per order.
+
+    ``ghost="zero"`` says the ghost cells are zeros the kernel may fill
+    itself (a Dirichlet-zero boundary on every face). The plan then
+    stages them in VMEM (``plan.ghost == "zero"``, its kernel fed the
+    unpadded operand) wherever :func:`zero_ghost_fits` admits its final
+    tile; the default tile is first derived for that staging
+    (:func:`default_block` with ``ghost="zero"``). Every other plan
+    comes back with ``ghost=None``, to be fed the padded operand.
     """
+    if ghost not in GHOSTS:
+        raise ValueError(f"ghost {ghost!r} not in {GHOSTS}")
     rank = ops.ndim
     if accuracy is None:
         accuracy = getattr(ops, "accuracy", 0)
@@ -849,11 +959,13 @@ def plan_stencil(
         )
 
     if block is None and (rank, strategy, batch, unroll) == (3, "swc", 1, 1):
-        block = default_block(
-            interior, radii, int(padded_shape[0]), int(n_out),
-            np.dtype(dtype).itemsize, fuse_steps, n_aux,
+        derive = functools.partial(
+            default_block, interior, radii, int(padded_shape[0]),
+            int(n_out), np.dtype(dtype).itemsize, fuse_steps, n_aux,
             taps=sum(len(spec.offsets) for spec in ops.ops),
         )
+        # A zero-ghost tile first; the padded staging's when none fits.
+        block = (derive(ghost=ghost) if ghost else None) or derive()
     if block is None:
         block = DEFAULT_BLOCKS[rank]
     if isinstance(block, int):
@@ -895,6 +1007,10 @@ def plan_stencil(
         unroll = 1
         tx = largest_divisor_leq(nx, block[-1])
     clamped.append(tx)
+    if ghost == "zero" and not zero_ghost_fits(
+        rank, strategy, batch, unroll, fuse_steps, clamped, interior
+    ):
+        ghost = None
 
     return StencilPlan(
         rank=rank,
@@ -910,6 +1026,7 @@ def plan_stencil(
         fuse_steps=int(fuse_steps),
         batch=int(batch),
         accuracy=int(accuracy),
+        ghost=ghost,
     )
 
 
@@ -921,6 +1038,7 @@ def plan_from_record(
     *,
     dtype: str = "float32",
     n_aux: int = 0,
+    ghost: str | None = None,
 ) -> StencilPlan | None:
     """Reconstruct the :class:`StencilPlan` a resolved tuning record
     lowers to — the warm-cache side of the ``strategy="auto"`` contract.
@@ -935,6 +1053,7 @@ def plan_from_record(
     built exactly as the kernel dispatch would build it, so
     ``plan.strategy_id``/``tuning_key()`` round-trip the decision —
     the left-inverse contract ``repro.analysis.keys`` audits per axis.
+    ``ghost`` is the boundary's offer, as :func:`plan_stencil` takes it.
     """
     strategy = record.resolved_strategy
     if strategy == "hwc":
@@ -952,5 +1071,5 @@ def plan_from_record(
     return plan_stencil(
         ops, padded, n_out, strategy=strategy,
         block=tuple(record.block), dtype=dtype, n_aux=n_aux,
-        unroll=unroll, fuse_steps=depth,
+        unroll=unroll, fuse_steps=depth, ghost=ghost,
     )

@@ -174,16 +174,19 @@ class AcousticSolver:
     ``um`` (step t − 1), each a (1, z, y, x) field stack, by
     ``n_steps`` steps in one jitted ``lax.scan`` and returns the new
     ``(u, um, t)`` and the ``(n_steps, ny, nx)`` receiver traces. Each
-    step is the Dirichlet pad, one kernel launch, the injection and the
-    receiver slice. The scan is unrolled: a rolled loop whose carry
+    step is one kernel launch, the injection and the receiver slice; the
+    Pallas ``swc`` launch fills the zero ghost cells in VMEM where its
+    plan can (``StencilPlan.ghost``), and other launches pad u first.
+    The scan is unrolled: a rolled loop whose carry
     swaps two levels makes XLA copy a level into the loop's buffers on
     every step, where unrolled each level is handed on as the buffer it
     is. A shot of thousands of steps is advanced in calls of a few.
 
     ``counts`` adds up, over every ``run``, the steps advanced and what
-    those steps executed: kernel launches, source injections and
-    receiver samples written, counted in the traced step program itself
-    (a step that lost its launch or its injection counts 0 of them).
+    those steps executed: kernel launches, source injections, receiver
+    samples written and ghost-cell pads, counted in the traced step
+    program itself (a step that lost its launch or its injection counts
+    0 of them).
     """
 
     def __init__(
@@ -217,7 +220,8 @@ class AcousticSolver:
         )
         self._run = jax.jit(self._advance, static_argnames="n_steps")
         self.counts = dict.fromkeys(
-            ("steps", "launches", "injections", "receiver_samples"), 0
+            ("steps", "launches", "injections", "receiver_samples", "pads"),
+            0,
         )
 
     def _step(self, carry, a, b, gain):
@@ -237,8 +241,9 @@ class AcousticSolver:
     @functools.cached_property
     def per_step(self) -> dict[str, int]:
         """What one traced step executes: kernel launches
-        (``pallas_call``), injections (``scatter-add``) and receiver
-        samples (the size of its trace row)."""
+        (``pallas_call``), injections (``scatter-add``), receiver
+        samples (the size of its trace row) and ghost-cell pads
+        (``pad``)."""
         lvl = jax.ShapeDtypeStruct((1,) + self.problem.shape, jnp.float32)
         t = jax.ShapeDtypeStruct((), jnp.int32)
         closed, (_, trace) = jax.make_jaxpr(
@@ -247,11 +252,14 @@ class AcousticSolver:
             ),
             return_shape=True,
         )(lvl, lvl, t)
-        c = _count_primitives(closed.jaxpr, {"pallas_call", "scatter-add"})
+        c = _count_primitives(
+            closed.jaxpr, {"pallas_call", "scatter-add", "pad"}
+        )
         return {
             "launches": c["pallas_call"],
             "injections": c["scatter-add"],
             "receiver_samples": math.prod(trace.shape),
+            "pads": c["pad"],
         }
 
     def run(self, u, um, t, n_steps: int):

@@ -146,7 +146,10 @@ class AuditShape:
     run. ``smoke`` mirrors the warm registry's smoke extents;
     ``full`` its benchmark extents (``python -m repro.analysis
     --full``). ``accuracy=0`` selects the hand-built cross-correlation
-    tap set instead of generated central differences."""
+    tap set instead of generated central differences. ``ghost`` lists
+    the boundary offers planned (``None``: a padded operand; ``"zero"``:
+    zero ghost cells the kernel may stage itself, planned for the
+    ``swc`` depth-1 plans only)."""
 
     name: str
     ndim: int
@@ -161,6 +164,7 @@ class AuditShape:
     fuse: tuple[int, ...] = (1,)
     unroll: tuple[int, ...] = (1,)
     batch: tuple[int, ...] = (1,)
+    ghost: tuple[str | None, ...] = (None,)
 
     def operator_set(self):
         import numpy as _np
@@ -186,9 +190,11 @@ class AuditShape:
 
         ops = self.operator_set()
         radii = ops.radius_per_axis()
-        for s, f, u, b in itertools.product(
-            self.strategies, self.fuse, self.unroll, self.batch
+        for s, f, u, b, g in itertools.product(
+            self.strategies, self.fuse, self.unroll, self.batch, self.ghost
         ):
+            if g is not None and (s, f, u, b) != ("swc", 1, 1, 1):
+                continue
             if s == "swc_stream" and (
                 self.ndim == 1 or self.n_aux or u != 1
             ):
@@ -210,7 +216,7 @@ class AuditShape:
             yield plan_stencil(
                 ops, lead + (self.n_f,) + padded, self.n_out,
                 strategy=s, dtype=self.dtype, n_aux=self.n_aux,
-                unroll=u, fuse_steps=f,
+                unroll=u, fuse_steps=f, ghost=g,
             ), ops
 
 
@@ -252,6 +258,10 @@ AUDIT_SHAPES: tuple[AuditShape, ...] = (
     AuditShape(
         "fig11/diffusion2d_bf16", 2, 6, (64, 128), (2048, 2048),
         dtype="bfloat16", strategies=("swc", "tc"),
+    ),
+    AuditShape(
+        "acoustic/shot-o8", 3, 8, (24, 32, 128), (512, 512, 512),
+        n_aux=3, strategies=("swc", "tc"), ghost=(None, "zero"),
     ),
 )
 

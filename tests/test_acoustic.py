@@ -5,6 +5,7 @@ Small sizes on the CPU (Pallas in interpret mode): a 24 × 32 × 128
 shot with a 4-point layer, the benchmark configuration's numerics
 otherwise, seeded velocity model and levels.
 """
+import dataclasses
 import json
 from pathlib import Path
 
@@ -16,7 +17,14 @@ import pytest
 import acoustic_reference as ref
 from repro.core.fusion import FusedStencilOp
 from repro.core.stencil import OperatorSet, derivative_operator_set
-from repro.physics.acoustic import AcousticProblem, AcousticSolver
+from repro.kernels.emit import fused_stencil_pallas
+from repro.kernels.plan import plan_stencil
+from repro.physics.acoustic import (
+    AcousticProblem,
+    AcousticSolver,
+    _count_primitives,
+    _phi as acoustic_phi,
+)
 
 ROOT = Path(__file__).resolve().parents[1]
 GRID = (24, 32, 128)
@@ -129,12 +137,15 @@ def test_counters_match_steps_and_receivers(shot):
     sol.run(u, um, jnp.int32(0), 3)
     sol.run(u, um, jnp.int32(3), 2)
     assert p.n_receivers == 12 * 60
+    # The swc launch fills its zero ghost cells itself: no pad.
     assert sol.counts == {
         "steps": 5, "launches": 5, "injections": 5,
-        "receiver_samples": 5 * p.n_receivers,
+        "receiver_samples": 5 * p.n_receivers, "pads": 0,
     }
-    # XLA's reference regime launches no kernel, and the counter says so.
-    assert AcousticSolver(p, v, strategy="hwc").per_step["launches"] == 0
+    # XLA's reference regime launches no kernel and pads u every step,
+    # and the counters say so.
+    hwc = AcousticSolver(p, v, strategy="hwc").per_step
+    assert (hwc["launches"], hwc["pads"]) == (0, 1)
 
 
 # -- the absorbing layer ------------------------------------------------------
@@ -225,3 +236,156 @@ def test_tuple_aux_equals_stacked_aux_bitwise(strategy, lead, spatial):
     two = op(f, aux=(rows(0, 2), rows(2, 3)))
     np.testing.assert_array_equal(np.asarray(split), np.asarray(stacked))
     np.testing.assert_array_equal(np.asarray(two), np.asarray(stacked))
+
+
+# -- zero ghost cells staged in the kernel ------------------------------------
+
+
+def _mixed_phi(d, aux):
+    """A φ over every operator of a set with mixed partials, so the
+    window's corners (a z face beside a y face) are read."""
+    return sum(d.values()) * aux[1:2] + aux[0:1] - aux[2:3]
+
+
+# (interior, tile, operators): grids of at least 3 blocks in z and y, so
+# the low-face, interior and high-face copies of both axes all run.
+ZERO_GHOST_CASES = {
+    # the shot's operators, one z chunk a tile
+    "acoustic-o8": ((24, 24, 128), (8, 8, 128), "acoustic"),
+    # mixed partials read the corners
+    "mixed-o4": ((24, 24, 256), (8, 8, 256), "mixed"),
+    # a body that walks its tile in z chunks (plan.z_chunk 4 of 8)
+    "chunked-o8": ((24, 96, 384), (8, 32, 384), "acoustic"),
+    # a z tile below the radius: two blocks copy at the low z face
+    "thin-z-o8": ((12, 24, 128), (2, 8, 128), "acoustic"),
+}
+
+
+@pytest.mark.parametrize("case", list(ZERO_GHOST_CASES))
+def test_zero_ghost_launch_equals_pad_and_launch(case):
+    interior, tile, kind = ZERO_GHOST_CASES[case]
+    if kind == "acoustic":
+        ops, phi = AcousticProblem(interior).operator_set(), acoustic_phi
+    else:
+        ops, phi = derivative_operator_set(3, 4, 0.5), _mixed_phi
+    radii = ops.radius_per_axis()
+    padded = (1,) + tuple(n + 2 * r for n, r in zip(interior, radii))
+    zero = plan_stencil(ops, padded, 1, n_aux=3, block=tile, ghost="zero")
+    plain = plan_stencil(ops, padded, 1, n_aux=3, block=tile)
+    assert (zero.ghost, plain.ghost) == ("zero", None)
+    assert min(zero.grid[:2]) >= 3
+    if case == "chunked-o8":
+        assert zero.z_chunk < zero.block[0]
+    k = jax.random.split(jax.random.key(11), 4)
+    u = jax.random.normal(k[0], (1,) + interior, jnp.float32)
+    aux = tuple(
+        jax.random.normal(kk, (1,) + interior, jnp.float32) for kk in k[1:]
+    )
+    got = fused_stencil_pallas(u, ops, phi, zero, aux=aux, interpret=True)
+    fp = jnp.pad(u, [(0, 0)] + [(r, r) for r in radii])
+    want = fused_stencil_pallas(fp, ops, phi, plain, aux=aux, interpret=True)
+    assert rel_gap(got, want) <= 1e-6
+
+
+@pytest.mark.parametrize("block", [(8, 8, 128), (4, 16, 128)])
+def test_solver_zero_ghost_tiles_match_reference(shot, block):
+    """The solver on tiles of several blocks in z and y, each staging
+    its zero ghosts in the kernel, against the plain reference."""
+    cfg, v, (u, um), (want, want_tr), _ = shot
+    sol = AcousticSolver(problem(cfg), v, strategy="swc", block=block)
+    assert sol.op.lowering_plan((1,) + GRID, n_aux=3).ghost == "zero"
+    assert sol.per_step["pads"] == 0
+    u, um, t, tr = sol.run(u, um, jnp.int32(T0), 16)
+    assert int(t) == T0 + 16
+    assert rel_gap(jnp.concatenate([u, um]), want) <= LEVEL_TOL
+    assert rel_gap(tr, want_tr) <= TRACE_TOL
+
+
+def _shot_ops():
+    return AcousticProblem(GRID).operator_set()
+
+
+def _self_map(d):
+    return d["val"] + 0.1 * (d["dzz"] + d["dyy"] + d["dxx"])
+
+
+# Ops the zero-ghost staging must leave on the pad path: (op kwargs,
+# leading axes of u, whether u⁻, a, b ride along as aux).
+PADDED_ROUTES = {
+    "periodic": ({"boundary_mode": "periodic"}, (), True),
+    "neumann": ({"boundary_mode": "neumann"}, (), True),
+    "batched": ({}, (2,), True),
+    "depth-2": ({"boundary_mode": "periodic", "fuse_steps": 2}, (), False),
+    "tc": ({"strategy": "tc"}, (), True),
+    "narrow-tile": ({"block": (8, 8, 64)}, (), True),
+    "boundary-weights": ({"boundary_weights": True}, (), True),
+}
+
+
+def _route(kw, lead, with_aux):
+    """(plan, pad primitives, the kernel's first operand's shape) of one
+    call of an op over the shot's operators on GRID."""
+    kw = {"boundary_mode": "dirichlet", "strategy": "swc", **kw}
+    op = FusedStencilOp(
+        _shot_ops(), _aux_phi if with_aux else _self_map, n_out=1, **kw
+    )
+    f = jnp.zeros(lead + (1,) + GRID, jnp.float32)
+    aux = (f, f, f) if with_aux else None
+    plan = op.lowering_plan(f.shape, n_aux=3 if with_aux else 0)
+    jaxpr = jax.make_jaxpr(lambda f: op(f, aux=aux))(f).jaxpr
+    (launch,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    pads = _count_primitives(jaxpr, {"pad"})["pad"]
+    return plan, pads, launch.invars[0].aval.shape[-3:]
+
+
+def test_zero_ghost_routes_the_shot():
+    """The shot's op stages its zero ghosts: no pad in its step, u fed
+    unpadded, a plan keyed apart from the padded plan of the same tile,
+    and at 512³ a tile spanning x."""
+    plan, pads, fed = _route({}, (), True)
+    assert plan.ghost == "zero" and plan.strategy_id.endswith(":gz")
+    assert (pads, fed) == (0, GRID)
+    padded = dataclasses.replace(plan, ghost=None)
+    assert padded.strategy_id != plan.strategy_id
+    assert padded.tuning_key() != plan.tuning_key()
+    sol = AcousticSolver(problem(config()), jnp.full(GRID, 2000.0))
+    assert (sol.per_step["pads"], sol.per_step["launches"]) == (0, 1)
+    big = sol.op.lowering_plan((1, 512, 512, 512), n_aux=3)
+    assert big.ghost == "zero" and big.block[-1] == 512
+
+
+@pytest.mark.parametrize("route", list(PADDED_ROUTES))
+def test_other_plans_keep_their_pad(route):
+    """Every other op pads u as before and feeds the kernel the padded
+    copy: a zero Dirichlet op through one ``pad``, periodic and
+    neumann through their wrap and edge fills."""
+    kw, lead, with_aux = PADDED_ROUTES[route]
+    plan, pads, fed = _route(kw, lead, with_aux)
+    depth = kw.get("fuse_steps", 1)
+    assert plan.ghost is None
+    assert fed == tuple(n + 8 * depth for n in GRID)
+    dirichlet = kw.get("boundary_mode", "dirichlet") == "dirichlet"
+    assert pads == (1 if dirichlet else 0)
+
+
+@pytest.mark.parametrize(
+    "kw",
+    [
+        {"strategy": "swc_stream"},
+        {"strategy": "tc"},
+        {"fuse_steps": 2},
+        {"batch": 2},
+        {"unroll": 2, "block": (8, 8, 64)},
+        {"block": (8, 8, 64)},
+        {"block": (8, 4, 128)},
+    ],
+    ids=["swc_stream", "tc", "depth-2", "batch-2", "unroll-2",
+         "narrow-x", "y-below-sublane"],
+)
+def test_planner_offers_zero_ghosts_only_where_they_fit(kw):
+    ops = _shot_ops()
+    depth = kw.get("fuse_steps", 1)
+    padded = (1,) + tuple(n + 8 * depth for n in GRID)
+    plan = plan_stencil(ops, padded, 1, ghost="zero", **kw)
+    assert plan.ghost is None
+    assert plan == plan_stencil(ops, padded, 1, **kw)
